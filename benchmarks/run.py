@@ -24,6 +24,7 @@ from pathlib import Path
 
 from benchmarks.common import Csv
 from benchmarks import kernel_bench, paper_tables, stream_bench
+from repro.core.compile_cache import enable_compile_cache
 
 _ROOT = Path(__file__).resolve().parent.parent
 BENCH_JSON = _ROOT / "BENCH_kernels.json"
@@ -68,6 +69,7 @@ TABLES = {
 
 
 def main() -> None:
+    enable_compile_cache(_ROOT)
     names = sys.argv[1:] or list(TABLES)
     csv = Csv()
     print("name,us_per_call,derived")

@@ -14,10 +14,12 @@ Run:  PYTHONPATH=src python examples/gnn_streaming.py [--graphs 50]
 """
 
 import argparse
+from pathlib import Path
 
 import jax
 
 from benchmarks.common import time_fn
+from repro.core.compile_cache import enable_compile_cache
 from repro.core.engine import GraphStreamEngine
 from repro.core.graph import build_graph_batch
 from repro.core.models import PAPER_GNN_CONFIGS, make_gnn
@@ -122,6 +124,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--graphs", type=int, default=30)
     args = ap.parse_args()
+    enable_compile_cache(Path(__file__).resolve().parents[1])
     for m in ("gin", "gcn", "gat"):
         stream(m, molhiv_like, "molhiv", args.graphs)
     stream("gin", hep_like, "hep", max(args.graphs // 3, 5))

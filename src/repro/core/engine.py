@@ -924,9 +924,10 @@ class GraphStreamEngine:
 
     def autotune_report(self) -> Dict[str, Dict[str, Any]]:
         """Per-bucket chosen (num_banks, edge_tile, impl) + candidate
-        timings + the device each bucket was tuned on, plus the bucket's
-        observed-load envelope (EWMA fill / device time / arrival rate),
-        drift re-tune count, and cold-program eviction count. Evicted
+        timings (and, under ``failed``, why each candidate that could not
+        run was dropped) + the device each bucket was tuned on, plus the
+        bucket's observed-load envelope (EWMA fill / device time / arrival
+        rate), drift re-tune count, and cold-program eviction count. Evicted
         buckets stay in the report — their tuning and history outlive the
         executable."""
         report: Dict[str, Dict[str, Any]] = {}
@@ -2329,17 +2330,21 @@ class GraphStreamEngine:
         candidates on the first batch of this bucket (on the executor that
         received it); cache and persist the winner for the whole pool."""
         timings: Dict[str, float] = {}
+        failed: Dict[str, str] = {}
         best_df, best_t, best_name = None, float("inf"), None
         for df in self._candidate_dataflows(key):
+            name = f"banks{df.num_banks}_tile{df.edge_tile}"
+            if df.impl != self.dataflow.impl:
+                name += f"_{df.impl}"
             run = self._make_run(df, donate=False)
             try:
                 jax.block_until_ready(run(ex.params, g))   # compile
                 t = min(self._time_once(run, ex.params, g) for _ in range(3))
-            except Exception:
-                continue                   # candidate invalid for this shape
-            name = f"banks{df.num_banks}_tile{df.edge_tile}"
-            if df.impl != self.dataflow.impl:
-                name += f"_{df.impl}"
+            except Exception as exc:
+                # invalid for this shape, or refused by the device's
+                # compiler: kept out of the race, but named in the report
+                failed[name] = f"{type(exc).__name__}: {exc}"[:500]
+                continue
             timings[name] = t * 1e6
             if t < best_t:
                 best_df, best_t, best_name = df, t, name
@@ -2356,6 +2361,8 @@ class GraphStreamEngine:
             load.tuned_device_s = best_t
         log: Dict[str, Any] = {"candidates_us": timings,
                                "device": ex.label}
+        if failed:
+            log["failed"] = failed
         if best_name is not None:
             log["winner"] = best_name
         if np.isfinite(best_t):

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import compat_axis_size, compat_shard_map
+from repro.distributed.sharding import compat_shard_map
 
 Array = jax.Array
 
@@ -46,7 +46,7 @@ def ring_shift(x: Array, axis_name: str, *, steps: int = 1,
     halo block for the peer s hops back). Must run inside ``shard_map``.
     """
     if size is None:
-        size = compat_axis_size(axis_name)
+        size = jax.lax.axis_size(axis_name)
     return jax.lax.ppermute(x, axis_name, ring_perm(size, steps=steps))
 
 

@@ -27,39 +27,22 @@ MeshAxis = Union[None, str, Tuple[str, ...]]
 
 
 # ---------------------------------------------------------------------------
-# jax version compatibility
+# shard_map / mesh helpers
 # ---------------------------------------------------------------------------
 
 def compat_shard_map(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    New jax exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Replication checking is off in both spellings — the manual collectives
-    here (ppermute rings, mask+psum broadcasts) confuse the checker.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
-def compat_axis_size(axis_name):
-    """``jax.lax.axis_size`` with a psum fallback for older jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.shard_map`` with replication checking off: the manual
+    collectives here (ppermute rings, mask+psum broadcasts) confuse the
+    checker."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def compat_make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            axis_shapes, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh`` with every axis of the Auto axis type."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 # ---------------------------------------------------------------------------

@@ -82,7 +82,7 @@ def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     q_tile: int = 128, kv_tile: int = 128,
-                    interpret: bool = True) -> Array:
+                    interpret: bool) -> Array:
     """q: (B, H, Sq, D); k/v: (B, H, Sk, D). Sq % q_tile == Sk % kv_tile == 0."""
     b, h, sq, d = q.shape
     sk = k.shape[2]

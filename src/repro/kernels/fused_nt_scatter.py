@@ -74,7 +74,7 @@ def _fused_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref,
 def fused_nt_scatter(x: Array, w1: Array, b1: Array, w2: Array, b2: Array,
                      senders: Array, receivers: Array, edge_mask: Array,
                      edge_feat: Array, *, node_tile: int = 32,
-                     interpret: bool = True) -> Array:
+                     interpret: bool) -> Array:
     """out[i] = sum_{e: dst(e)=i} relu(MLP(x)[src(e)] + edge_feat[e]).
 
     x: (N, D_in); MLP: D_in -> D_ff -> D. edge_feat: (E, D).

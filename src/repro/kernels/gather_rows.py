@@ -46,7 +46,7 @@ def _gather_kernel(idx_ref, mask_ref, y_ref, out_ref, *,
 @functools.partial(
     jax.jit, static_argnames=("idx_tile", "num_banks", "interpret"))
 def gather_rows(y: Array, idx: Array, mask: Array, *, idx_tile: int = 128,
-                num_banks: int = 4, interpret: bool = True) -> Array:
+                num_banks: int = 4, interpret: bool) -> Array:
     """out[i] = y[idx[i]] (masked rows -> 0). y: (N, D); idx/mask: (S,).
 
     S % idx_tile == 0 and N % num_banks == 0 (pad at the call site).

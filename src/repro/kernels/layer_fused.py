@@ -66,7 +66,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mp_pipeline import (BIG, _gather_phi_tile,
                                        _src_weight_mode, apply_fusable_phi)
-from repro.kernels.mp_scatter import _ceil_to, _route_matrix, pad_edge_stream
+from repro.kernels.mp_scatter import (ROUTE_PRECISION, _ceil_to, _route_matrix,
+                                      pad_edge_stream)
 
 Array = jax.Array
 
@@ -123,11 +124,11 @@ def _layer_fused_kernel(*refs, bank_size: int, edge_tile: int, n_pad: int,
     if epilogue == "scalers":
         acc_s, acc_sq, acc_mx, acc_mn = scratch
         acc_s[...] += jax.lax.dot_general(
-            route, msg, dimension_numbers=dn,
+            route, msg, dimension_numbers=dn, precision=ROUTE_PRECISION,
             preferred_element_type=jnp.float32)
         acc_sq[...] += jax.lax.dot_general(
             route, msg * msg, dimension_numbers=dn,
-            preferred_element_type=jnp.float32)
+            precision=ROUTE_PRECISION, preferred_element_type=jnp.float32)
         # keyed max/min (mp_pipeline's finite additive-key formulation)
         key = (route - 1.0) * BIG                     # (edge_tile, bank)
         acc_mx[...] = jnp.maximum(
@@ -136,7 +137,7 @@ def _layer_fused_kernel(*refs, bank_size: int, edge_tile: int, n_pad: int,
             acc_mn[...], jnp.min(msg[:, None, :] - key[:, :, None], axis=0))
     else:
         scratch[0][...] += jax.lax.dot_general(
-            route, msg, dimension_numbers=dn,
+            route, msg, dimension_numbers=dn, precision=ROUTE_PRECISION,
             preferred_element_type=jnp.float32)
 
     def _mlp_out(z):
@@ -209,7 +210,7 @@ def layer_fused(x: Array, senders: Array, receivers: Array, edge_mask: Array,
                 field_wsum: Array = None,
                 w2: Array = None, b2: Array = None,
                 out_activation: str = "none", edge_tile: int = 128,
-                num_banks: int = 4, interpret: bool = True) -> Array:
+                num_banks: int = 4, interpret: bool) -> Array:
     """One-launch GNN layer: gather + phi + aggregate + NT update.
 
     Per edge, phi is the fusable form of ``mp_pipeline``

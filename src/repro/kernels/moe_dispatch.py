@@ -25,7 +25,7 @@ Array = jax.Array
 
 def moe_dispatch(x: Array, token_ids: Array, slot: Array, own: Array,
                  num_slots: int, *, edge_tile: int = 128,
-                 num_banks: int = 4, interpret: bool = True) -> Array:
+                 num_banks: int = 4, interpret: bool) -> Array:
     """Build the (num_slots, d) expert buffer from routed tokens.
 
     x: (T, d); token_ids/slot/own: (T*k,) — raw router output order,
@@ -38,7 +38,7 @@ def moe_dispatch(x: Array, token_ids: Array, slot: Array, own: Array,
 
 def moe_combine(y: Array, token_ids: Array, slot: Array, own: Array,
                 weights: Array, num_tokens: int, *, edge_tile: int = 128,
-                num_banks: int = 4, interpret: bool = True) -> Array:
+                num_banks: int = 4, interpret: bool) -> Array:
     """out[t] = sum_assignments w * y[slot]: banked gather then banked
     scatter-add back to tokens."""
     gathered = gather_rows(y, slot, own, idx_tile=edge_tile,

@@ -50,8 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.mp_scatter import (MULTI_STATS, _ceil_to, _route_matrix,
-                                      pad_edge_stream)
+from repro.kernels.mp_scatter import (MULTI_STATS, ROUTE_PRECISION, _ceil_to,
+                                      _route_matrix, pad_edge_stream)
 
 Array = jax.Array
 
@@ -64,6 +64,23 @@ BIG = 1e30
 # attention is on: per dest-node per head, the running keyed max and the
 # online-rescaled denominator (flash attention's (m, l) pair, DESIGN.md §6).
 ATT_STATS = ("att_max", "att_denom")
+
+
+def _head_lanes(d: int, heads: int) -> Array:
+    """(1, d) map of each lane to its head: lane // head_dim."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, d), 1) // (d // heads)
+
+
+def _expand_heads(v: Array, lane_head: Array) -> Array:
+    """(R, H) -> (R, H·head_dim): each head's column over its lanes.
+
+    A per-head select rather than a product with an (R, H, head_dim)
+    reshape of the accumulator, which the TPU compiler refuses for
+    head_dim < 128 lanes; the values are the same."""
+    out = jnp.zeros((v.shape[0], lane_head.shape[1]), v.dtype)
+    for h in range(v.shape[1]):
+        out = jnp.where(lane_head == h, v[:, h:h + 1], out)
+    return out
 
 
 def _gather_phi_tile(y_ref, snd, valid, sw_ref, et_ref, b_ref, *,
@@ -83,6 +100,7 @@ def _gather_phi_tile(y_ref, snd, valid, sw_ref, et_ref, b_ref, *,
     lanes = jax.lax.broadcasted_iota(jnp.int32, (edge_tile, n_pad), 1)
     g_route = ((lanes == snd[:, None]) & valid[:, None]).astype(jnp.float32)
     src = jax.lax.dot(g_route, y_ref[...].astype(jnp.float32),
+                      precision=ROUTE_PRECISION,
                       preferred_element_type=jnp.float32)   # (edge_tile, D)
 
     # --- phi, in-register (masked rows may hold garbage from the additive
@@ -172,28 +190,45 @@ def _mp_pipeline_kernel(*refs, bank_size: int, edge_tile: int, n_pad: int,
         # exp from overflowing on unowned -BIG lanes before the route
         # zeroes them.
         a_s = jax.lax.dot(g_route, as_ref[...].astype(jnp.float32),
+                          precision=ROUTE_PRECISION,
                           preferred_element_type=jnp.float32)  # (tile, H)
         a_d = jax.lax.dot(route, ad_in_ref[...].astype(jnp.float32),
+                          precision=ROUTE_PRECISION,
                           preferred_element_type=jnp.float32)  # (tile, H)
         logits = a_s + a_d
         logits = jnp.where(logits >= 0.0, logits, att_slope * logits)
         key = (route - 1.0) * BIG                    # (tile, bank)
-        keyed = logits[:, None, :] + key[:, :, None]  # (tile, bank, H)
-        m_old = out["att_max"][...]
-        m_new = jnp.maximum(m_old, jnp.max(keyed, axis=0))
-        corr = jnp.exp(m_old - m_new)                # (bank, H), ≤ 1
-        p = (jnp.exp(jnp.minimum(keyed - m_new[None], 0.0))
-             * route[:, :, None])                    # (tile, bank, H)
-        out["att_denom"][...] = (out["att_denom"][...] * corr
-                                 + jnp.sum(p, axis=0))
-        out["att_max"][...] = m_new
-        hd = msg.shape[1] // att_heads
-        msg_h = msg.reshape(edge_tile, att_heads, hd)
-        acc = out["sum"][...].reshape(bank_size, att_heads, hd)
-        num = jnp.einsum("ebh,ehd->bhd", p, msg_h,
-                         preferred_element_type=jnp.float32)
-        out["sum"][...] = (acc * corr[:, :, None] + num).reshape(
-            bank_size, -1)
+        # one head at a time on (tile, bank) planes: the TPU compiler
+        # refuses the (tile, H·head_dim) -> (tile, H, head_dim) reshape a
+        # per-head einsum needs, so no 3-D per-head value is formed. The
+        # carries are read and written transposed, (H, bank), so each
+        # head's row lines up with the bank lanes of its keyed plane.
+        lane_head = _head_lanes(msg.shape[1], att_heads)
+        head_row = jax.lax.broadcasted_iota(
+            jnp.int32, (att_heads, bank_size), 0)
+        m_old = out["att_max"][...].T                # (H, bank)
+        d_old = out["att_denom"][...].T
+        m_new, d_new, corr = m_old, d_old, m_old
+        num = jnp.zeros(out["sum"].shape, jnp.float32)
+        for h in range(att_heads):
+            keyed = logits[:, h:h + 1] + key         # (tile, bank)
+            m_h = jnp.maximum(m_old[h:h + 1],
+                              jnp.max(keyed, axis=0, keepdims=True))
+            corr_h = jnp.exp(m_old[h:h + 1] - m_h)   # (1, bank), ≤ 1
+            p = jnp.exp(jnp.minimum(keyed - m_h, 0.0)) * route
+            d_h = (d_old[h:h + 1] * corr_h
+                   + jnp.sum(p, axis=0, keepdims=True))
+            m_new = jnp.where(head_row == h, m_h, m_new)
+            d_new = jnp.where(head_row == h, d_h, d_new)
+            corr = jnp.where(head_row == h, corr_h, corr)
+            num = num + jax.lax.dot_general(
+                p, jnp.where(lane_head == h, msg, 0.0),
+                dimension_numbers=dn, precision=ROUTE_PRECISION,
+                preferred_element_type=jnp.float32)
+        out["att_max"][...] = m_new.T
+        out["att_denom"][...] = d_new.T
+        out["sum"][...] = (out["sum"][...] * _expand_heads(corr.T, lane_head)
+                           + num)
 
         @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
         def _att_normalize():
@@ -203,16 +238,15 @@ def _mp_pipeline_kernel(*refs, bank_size: int, edge_tile: int, n_pad: int,
             den = out["att_denom"][...]
             wgt = jnp.where(den > 0.0,
                             1.0 / jnp.maximum(den, 1e-16), 0.0)
-            s = out["sum"][...].reshape(bank_size, att_heads, hd)
-            out["sum"][...] = (s * wgt[:, :, None]).reshape(bank_size, -1)
+            out["sum"][...] = out["sum"][...] * _expand_heads(wgt, lane_head)
     elif "sum" in out:
         out["sum"][...] += jax.lax.dot_general(
-            route, msg, dimension_numbers=dn,
+            route, msg, dimension_numbers=dn, precision=ROUTE_PRECISION,
             preferred_element_type=jnp.float32)
     if "sumsq" in out:
         out["sumsq"][...] += jax.lax.dot_general(
             route, msg * msg, dimension_numbers=dn,
-            preferred_element_type=jnp.float32)
+            precision=ROUTE_PRECISION, preferred_element_type=jnp.float32)
     if "count" in out:
         out["count"][...] += jnp.sum(route, axis=0)[:, None]
     if "max" in out or "min" in out:
@@ -241,7 +275,7 @@ def mp_pipeline(x: Array, senders: Array, receivers: Array, edge_mask: Array,
                 activation: str = "none", att_src: Array = None,
                 att_dst: Array = None, att_slope: float = 0.2,
                 edge_tile: int = 128, num_banks: int = 4,
-                interpret: bool = True):
+                interpret: bool):
     """One-launch edge phase: gather + fusable phi + multi-stat scatter.
 
     ``x`` is the (num_nodes, D) node buffer; phi for edge e is

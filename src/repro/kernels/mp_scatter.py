@@ -46,6 +46,13 @@ Array = jax.Array
 # Statistic names in the fixed output order of mp_scatter_multi.
 MULTI_STATS = ("sum", "sumsq", "count", "max", "min")
 
+# Precision of every routing matmul (one-hot gather / scatter): the f32
+# contract. Mosaic's default contract rounds f32 operands toward bf16, so
+# a one-hot gather would hand back bf16-rounded rows and a scatter-sum
+# would add bf16-rounded messages — errors the XLA segment-op path never
+# makes. A one-hot row times an f32 value is exact under the f32 contract.
+ROUTE_PRECISION = jax.lax.Precision.HIGHEST
+
 
 def _route_matrix(recv, mask, bank, bank_size, edge_tile):
     """Boolean one-hot routing matrix (edge_tile, bank_size) for this bank."""
@@ -53,6 +60,13 @@ def _route_matrix(recv, mask, bank, bank_size, edge_tile):
     own = (local >= 0) & (local < bank_size) & (mask != 0)
     lanes = jax.lax.broadcasted_iota(jnp.int32, (edge_tile, bank_size), 1)
     return (lanes == local[:, None]) & own[:, None]
+
+
+def _route_select(route_b):
+    """(edge_tile, bank_size, 1) boolean select from a routing matrix,
+    built through f32: the TPU compiler refuses the reshape of a boolean
+    vector."""
+    return route_b.astype(jnp.float32)[:, :, None] > 0.0
 
 
 def _ceil_to(x: int, mult: int) -> int:
@@ -104,6 +118,7 @@ def _mp_scatter_kernel(recv_ref, mask_ref, msg_ref, out_ref, *,
     out_ref[...] += jax.lax.dot_general(
         route.astype(jnp.float32), msg,
         dimension_numbers=(((0,), (0,)), ((), ())),   # route^T @ msg
+        precision=ROUTE_PRECISION,
         preferred_element_type=jnp.float32,
     )
 
@@ -115,7 +130,7 @@ def _mp_scatter_kernel(recv_ref, mask_ref, msg_ref, out_ref, *,
 )
 def mp_scatter(msg: Array, receivers: Array, edge_mask: Array,
                num_nodes: int, *, node_tile: int = 8, edge_tile: int = 128,
-               num_banks: int = 4, interpret: bool = True) -> Array:
+               num_banks: int = 4, interpret: bool) -> Array:
     """Scatter-sum `msg` (E, D) into (num_nodes, D) via dest-banked routing.
 
     Accumulates in float32, returns ``msg.dtype``. E is padded internally to
@@ -176,16 +191,16 @@ def _mp_scatter_multi_kernel(recv_ref, mask_ref, msg_ref, *out_refs,
 
     if "sum" in refs:
         refs["sum"][...] += jax.lax.dot_general(
-            route, msg, dimension_numbers=dn,
+            route, msg, dimension_numbers=dn, precision=ROUTE_PRECISION,
             preferred_element_type=jnp.float32)
     if "sumsq" in refs:
         refs["sumsq"][...] += jax.lax.dot_general(
             route, msg * msg, dimension_numbers=dn,
-            preferred_element_type=jnp.float32)
+            precision=ROUTE_PRECISION, preferred_element_type=jnp.float32)
     if "count" in refs:
         refs["count"][...] += jnp.sum(route, axis=0)[:, None]
     if "max" in refs or "min" in refs:
-        sel = route_b[:, :, None]                     # (edge_tile, bank, 1)
+        sel = _route_select(route_b)                  # (edge_tile, bank, 1)
         if "max" in refs:
             tile = jnp.where(sel, msg[:, None, :], -jnp.inf)
             refs["max"][...] = jnp.maximum(refs["max"][...],
@@ -204,7 +219,7 @@ def _mp_scatter_multi_kernel(recv_ref, mask_ref, msg_ref, *out_refs,
 def mp_scatter_multi(msg: Array, receivers: Array, edge_mask: Array,
                      num_nodes: int, *, stats, node_tile: int = 8,
                      edge_tile: int = 128, num_banks: int = 4,
-                     interpret: bool = True):
+                     interpret: bool):
     """One edge-stream sweep feeding multiple per-node accumulators.
 
     ``stats`` is a subset of MULTI_STATS. Returns ``{name: f32 array}``:

@@ -48,7 +48,7 @@ def _nt_mlp_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, out_ref, acc_ref):
     jax.jit, static_argnames=("node_tile", "k_tile", "interpret"))
 def nt_mlp(x: Array, w1: Array, b1: Array, w2: Array, b2: Array, *,
            node_tile: int = 128, k_tile: int = 128,
-           interpret: bool = True) -> Array:
+           interpret: bool) -> Array:
     """y = relu(x @ w1 + b1) @ w2 + b2 with the hidden matrix kept in VMEM.
 
     x: (N, D_in), w1: (D_in, D_ff), w2: (D_ff, D_out).

@@ -31,7 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.mp_scatter import _ceil_to, _route_matrix, pad_edge_stream
+from repro.kernels.mp_scatter import (ROUTE_PRECISION, _ceil_to, _route_matrix,
+                                      _route_select, pad_edge_stream)
 
 Array = jax.Array
 
@@ -49,7 +50,8 @@ def _stats_kernel(recv_ref, mask_ref, logit_ref, m_ref, d_ref, *,
     recv = recv_ref[...].reshape(edge_tile)
     mask = mask_ref[...].reshape(edge_tile)
 
-    sel = _route_matrix(recv, mask, bank, bank_size, edge_tile)[:, :, None]
+    sel = _route_select(
+        _route_matrix(recv, mask, bank, bank_size, edge_tile))  # (tile, bank, 1)
 
     # per-node max of this tile: (edge_tile, bank, H) mask-select -> max
     tile = jnp.where(sel, logit[:, None, :], -jnp.inf)
@@ -82,8 +84,10 @@ def _norm_kernel(recv_ref, mask_ref, logit_ref, m_ref, d_ref, out_ref, *,
     route = (lanes == recv[:, None]).astype(jnp.float32)
     dn = (((1,), (0,)), ((), ()))                     # route @ stats
     gm = jax.lax.dot_general(route, m_clean, dimension_numbers=dn,
+                             precision=ROUTE_PRECISION,
                              preferred_element_type=jnp.float32)
     gd = jax.lax.dot_general(route, d_ref[...], dimension_numbers=dn,
+                             precision=ROUTE_PRECISION,
                              preferred_element_type=jnp.float32)
 
     valid = (mask != 0)[:, None] & (gd > 0.0)
@@ -97,7 +101,7 @@ def _norm_kernel(recv_ref, mask_ref, logit_ref, m_ref, d_ref, out_ref, *,
 )
 def seg_softmax(logits: Array, receivers: Array, edge_mask: Array,
                 num_nodes: int, *, edge_tile: int = 128, num_banks: int = 4,
-                interpret: bool = True) -> Array:
+                interpret: bool) -> Array:
     """Streaming per-destination softmax. logits: (E,) or (E, H)."""
     squeeze = logits.ndim == 1
     e = logits.shape[0]

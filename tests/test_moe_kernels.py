@@ -18,7 +18,8 @@ def test_gather_rows_sweep(n, d, s, tile, banks):
     y = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     idx = jnp.asarray(rng.integers(0, n, size=s).astype(np.int32))
     mask = jnp.asarray(rng.random(s) < 0.8)
-    out = gather_rows(y, idx, mask, idx_tile=tile, num_banks=banks)
+    out = gather_rows(y, idx, mask, idx_tile=tile, num_banks=banks,
+                      interpret=True)
     np.testing.assert_allclose(out, gather_rows_ref(y, idx, mask),
                                atol=1e-5, rtol=1e-5)
 
@@ -48,12 +49,12 @@ def test_moe_kernel_path_matches_jnp_dispatch():
     # kernel path
     buf = moe_dispatch(x, jnp.asarray(st), jnp.asarray(slot),
                        jnp.asarray(own), e_loc * cap, edge_tile=32,
-                       num_banks=2)
+                       num_banks=2, interpret=True)
     y = jnp.einsum("ecd,edf->ecf", buf.reshape(e_loc, cap, d), w_expert)
     y = jnp.maximum(y, 0.0).reshape(e_loc * cap, d)
     out = moe_combine(y, jnp.asarray(st), jnp.asarray(slot),
                       jnp.asarray(own), jnp.asarray(sw), t, edge_tile=32,
-                      num_banks=2)
+                      num_banks=2, interpret=True)
 
     # jnp reference (same math, dense per token)
     ref = np.zeros((t, d), np.float32)
@@ -77,9 +78,10 @@ def test_dispatch_is_permutation_invariant():
     slot = rng.permutation(64).astype(np.int32)      # unique slots
     own = rng.random(64) < 0.8
     a = moe_dispatch(x, jnp.asarray(st), jnp.asarray(slot),
-                     jnp.asarray(own), slots, edge_tile=32, num_banks=2)
+                     jnp.asarray(own), slots, edge_tile=32, num_banks=2,
+                     interpret=True)
     perm = rng.permutation(64)
     b = moe_dispatch(x, jnp.asarray(st[perm]), jnp.asarray(slot[perm]),
                      jnp.asarray(own[perm]), slots, edge_tile=32,
-                     num_banks=2)
+                     num_banks=2, interpret=True)
     np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)

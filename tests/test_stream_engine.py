@@ -157,6 +157,33 @@ def test_autotune_candidates_include_pipeline_and_cache_roundtrips_impl(
     np.testing.assert_allclose(base, out, atol=1e-5, rtol=1e-5)
 
 
+def test_autotune_report_names_failed_candidates(monkeypatch):
+    """A candidate the compiler refuses stays out of the race, and its
+    reason shows in the report instead of the candidate simply vanishing."""
+    g = next(molhiv_like(seed=0, n_graphs=1))
+    with _make_engine("gin", max_batch=1, autotune=True) as eng:
+        make_run = eng._make_run
+
+        def refusing(df, donate=True):
+            if df.impl != "pipeline":
+                return make_run(df, donate)
+
+            def run(params, graph):
+                raise RuntimeError("kernel refused by the compiler")
+            return run
+
+        monkeypatch.setattr(eng, "_make_run", refusing)
+        out = eng.process(g.node_feat, g.senders, g.receivers, g.edge_feat,
+                          g.node_pos)
+        assert np.all(np.isfinite(out))
+        (entry,) = eng.autotune_report().values()
+        (name, reason), = entry["failed"].items()
+        assert name.endswith("_pipeline")
+        assert reason == "RuntimeError: kernel refused by the compiler"
+        assert name not in entry["candidates_us"]
+        assert entry["candidates_us"]          # the others were timed
+
+
 def test_warmup_all_precompiles_configured_buckets():
     with _make_engine("gin", buckets=(32, 64), max_batch=2) as eng:
         keys = eng.warmup_all()

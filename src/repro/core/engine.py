@@ -62,6 +62,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.errors import (BatchFailed, DeadlineExceeded, EngineClosed,
                                EngineError, ExecutorDead, GraphTooLarge,
                                InvalidGraph, InvalidRequest, ParamUpdateFailed,
@@ -668,6 +669,12 @@ class GraphStreamEngine:
         an already-expired request is never admitted, let alone
         dispatched.
         """
+        with spans.span(spans.SUBMIT) as span:
+            return self._submit(span, node_feat, senders, receivers,
+                                edge_feat, node_pos, record, queue, deadline)
+
+    def _submit(self, span, node_feat, senders, receivers, edge_feat,
+                node_pos, record, queue, deadline) -> Future:
         if edge_feat is None and self.cfg.edge_feat_dim != 1:
             raise InvalidRequest("model expects edge features")
         if deadline is not None and deadline <= 0:
@@ -682,6 +689,7 @@ class GraphStreamEngine:
         with self._cv:
             req_id = self._req_seq
             self._req_seq += 1
+        span.set_metadata(req=req_id)
         if self._faults is not None:
             self._faults.on_submit(req_id)       # may raise InjectedOOM
             # chaos site: a "buggy client" corrupts its own arrays BEFORE
@@ -696,13 +704,14 @@ class GraphStreamEngine:
             # edge_feat_dim 1 means "model takes no edge features" — any
             # provided width is legal there (it is ignored), so the width
             # check only binds when the model consumes edge features.
-            reason = check_graph(
-                node_feat, senders, receivers, edge_feat, node_pos,
-                node_feat_dim=self.cfg.node_feat_dim,
-                edge_feat_dim=(self.cfg.edge_feat_dim
-                               if self.cfg.edge_feat_dim != 1 else None),
-                pos_dim=self.cfg.pos_dim,
-                require_finite=self._require_finite)
+            with spans.span(spans.VALIDATE):
+                reason = check_graph(
+                    node_feat, senders, receivers, edge_feat, node_pos,
+                    node_feat_dim=self.cfg.node_feat_dim,
+                    edge_feat_dim=(self.cfg.edge_feat_dim
+                                   if self.cfg.edge_feat_dim != 1 else None),
+                    pos_dim=self.cfg.pos_dim,
+                    require_finite=self._require_finite)
             if reason is not None:
                 with self._cv:
                     self.stats.invalid_rejects += 1
@@ -1015,6 +1024,9 @@ class GraphStreamEngine:
         while True:
             picked = None          # (queue_name, pb, exclude_index)
             to_fail: List[Tuple[_Request, BaseException]] = []
+            # one span per pass of the placer, from its wake to the placed
+            # batch's hand-off; a pass that places nothing ends at its wait
+            place = spans.span(spans.PLACE).__enter__()
             with self._cv:
                 while picked is None:
                     now = time.perf_counter()
@@ -1091,6 +1103,7 @@ class GraphStreamEngine:
                         if (self._closed
                                 and not self._scheduler.ready_batches
                                 and not self._retry_heap):
+                            place.__exit__(None, None, None)
                             return
                         # ready/retrying batches remain, no capacity (or a
                         # retry not yet due): wait below
@@ -1110,8 +1123,10 @@ class GraphStreamEngine:
                                 self._scheduler.preempt_splits)
                         break
                     wake = self._next_wake_locked(has_cap)
+                    place.__exit__(None, None, None)
                     self._cv.wait(timeout=None if wake is None
                                   else max(wake - now, 0.0))
+                    place = spans.span(spans.PLACE).__enter__()
                 if picked is not None:
                     # last-moment shedding: expired members of the popped
                     # batch never reach a device
@@ -1124,7 +1139,13 @@ class GraphStreamEngine:
             for req, exc in to_fail:
                 _resolve(req.future, exc=exc)
             if picked is not None:
-                self._place(*picked)
+                placed = self._place(*picked)
+                if placed is not None and place is not spans.OFF:
+                    place.set_metadata(
+                        batch=placed[0], dev=placed[1],
+                        reqs=spans.id_list(it.payload.req_id
+                                           for it in picked[1].items))
+            place.__exit__(None, None, None)
 
     def _next_wake_locked(self, has_cap: bool) -> Optional[float]:
         """Earliest reason for the placer to wake: a packer flush
@@ -1149,11 +1170,13 @@ class GraphStreamEngine:
         return min(cands) if cands else None
 
     def _place(self, queue_name: str, pb: PackedBatch,
-               exclude: Optional[int] = None) -> None:
+               exclude: Optional[int] = None) -> Optional[Tuple[int, int]]:
         """Least-backlog placement across executors with pipeline room
         (ties: lowest index); dead executors are never chosen while an
         alive one exists, and a retry avoids the executor it failed on
-        (``exclude``) when any alternative is alive."""
+        (``exclude``) when any alternative is alive. Returns the batch's
+        dispatch id and its device's id, or ``None`` if it was not
+        handed to an executor."""
         with self._cv:
             free = [ex for ex in self._executors
                     if ex.index not in self._wide_reserved]
@@ -1167,13 +1190,13 @@ class GraphStreamEngine:
                 # come back when the gang releases
                 self._push_retry_locked(queue_name, pb, delay=0.001,
                                         exclude=exclude)
-                return
+                return None
             if not cands:          # whole pool dead: nothing can run this
                 reqs = self._take_requests_locked(pb)
                 self.stats.record_failure(queue=queue_name, failed=len(reqs))
             else:
                 ex = min(cands, key=lambda e: (e.backlog, e.index))
-                pb.dispatch_id = self._dispatch_seq
+                dispatch_id = pb.dispatch_id = self._dispatch_seq
                 self._dispatch_seq += 1
                 self._inflight[pb.dispatch_id] = _Inflight(
                     queue=queue_name, batch=pb, ex=ex,
@@ -1185,8 +1208,9 @@ class GraphStreamEngine:
                                request_ids=tuple(r.req_id for r in reqs))
             for req in reqs:
                 _resolve(req.future, exc=exc)
-            return
+            return None
         ex.submit(queue_name, pb)
+        return dispatch_id, ex.device.id
 
     # ------------------------------------------------------------------
     # wide placement: gang scheduling + the gang runner (DESIGN.md §10)
@@ -1267,7 +1291,14 @@ class GraphStreamEngine:
 
     def _run_wide(self, wreq: _WideRequest,
                   gang: List[DeviceExecutor]) -> None:
-        """Run one wide request on its reserved gang (own thread).
+        """Run one wide request on its reserved gang (own thread), in one
+        ``flowgnn.wide`` span."""
+        with spans.span(spans.WIDE, req=wreq.req.req_id):
+            self._run_gang(wreq, gang)
+
+    def _run_gang(self, wreq: _WideRequest,
+                  gang: List[DeviceExecutor]) -> None:
+        """The body of ``_run_wide``.
 
         Fault semantics (DESIGN.md §10): a gang-member death before,
         during, or after the collective invalidates the WHOLE gang — a
@@ -1545,14 +1576,15 @@ class GraphStreamEngine:
     def _handle_completion(self, ex: DeviceExecutor,
                            done: CompletedBatch) -> None:
         pb = done.batch
-        with self._cv:
-            if pb.dispatch_id is not None:
-                if self._inflight.pop(pb.dispatch_id, None) is None:
-                    return      # superseded (watchdog/drain-timeout/close)
-        if done.err is None:
-            self._complete_ok(ex, done)
-        else:
-            self._complete_err(ex, done)
+        with spans.span(spans.RESOLVE, batch=pb.dispatch_id):
+            with self._cv:
+                if pb.dispatch_id is not None:
+                    if self._inflight.pop(pb.dispatch_id, None) is None:
+                        return  # superseded (watchdog/drain-timeout/close)
+            if done.err is None:
+                self._complete_ok(ex, done)
+            else:
+                self._complete_err(ex, done)
 
     def _complete_ok(self, ex: DeviceExecutor, done: CompletedBatch) -> None:
         pb = done.batch
@@ -2186,8 +2218,13 @@ class GraphStreamEngine:
         # across candidates (and the winner's real dispatch), so its buffers
         # must survive every timing call.
         argnums = (1,) if donate and jax.default_backend() != "cpu" else ()
-        return jax.jit(lambda params, graph: apply(params, graph, cfg, df),
-                       donate_argnums=argnums)
+
+        # a stable name: the program's modules read ``jit_flowgnn_forward``
+        # on the device planes of a profiler trace
+        def flowgnn_forward(params, graph):
+            return apply(params, graph, cfg, df)
+
+        return jax.jit(flowgnn_forward, donate_argnums=argnums)
 
     def _ensure_program(self, ex: DeviceExecutor, key: BucketKey,
                         g: GraphBatch):
@@ -2207,6 +2244,13 @@ class GraphStreamEngine:
         if run is not None:
             ex.touched[key] = next(self._touch)
             return run
+        with spans.span(spans.COMPILE, bucket=spans.bucket_name(key)):
+            return self._ensure_program_locked(ex, key, g)
+
+    def _ensure_program_locked(self, ex: DeviceExecutor, key: BucketKey,
+                               g: GraphBatch):
+        """``_ensure_program``'s miss path: tune, trace and install the
+        bucket's program under the compile lock."""
         with self._compile_lock:
             run = ex.compiled.get(key)
             if run is not None:

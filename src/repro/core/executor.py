@@ -34,6 +34,11 @@ work on survivors. ``stop(timeout=...)`` bounds every join, so a wedged
 worker can never block shutdown; ``mark_dead`` is the engine watchdog's
 entry point for executors that are stuck rather than crashed.
 
+Each stage of a batch opens a span on the profiler's clock
+(``core/spans.py``): build, launch (with compile on a program's first
+call here) and stage on the dispatch thread; device wait, fetch and
+unpack on the completer, all carrying the batch's dispatch id.
+
 ``backlog`` (graphs submitted here and not yet completed) is what the
 engine's least-backlog placement reads; ``device_s`` in ``CompletedBatch``
 is *marginal* device-busy time per executor, so overlapped batches on one
@@ -45,12 +50,14 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
+from repro.core import spans
 from repro.core.errors import ExecutorDead
 from repro.core.packing import PackedBatch
 
@@ -120,6 +127,9 @@ class DeviceExecutor:
         # this namespace (DESIGN.md §5).
         self.compiled: Dict[BucketKey, Any] = {}
         self.touched: Dict[BucketKey, int] = {}
+        # programs called at least once here: the first call traces and
+        # compiles (weak, so an evicted program is still freed)
+        self._ran: "weakref.WeakSet[Any]" = weakref.WeakSet()
 
         self._build_fn = build_fn
         self._program_fn = program_fn
@@ -290,7 +300,17 @@ class DeviceExecutor:
     def warm(self, key: BucketKey, g) -> None:
         """Compile (and run once) the bucket's program on this device."""
         run = self._program_fn(self, key, g)
-        jax.block_until_ready(run(self.params, g))
+        jax.block_until_ready(self._call(run, key, self.params, g))
+
+    def _call(self, run, key: BucketKey, params, g):
+        """``run(params, g)``; a program's first call here traces and
+        compiles it, inside a ``flowgnn.compile`` span."""
+        if run in self._ran:
+            return run(params, g)
+        with spans.span(spans.COMPILE, bucket=spans.bucket_name(key)):
+            out = run(params, g)
+        self._ran.add(run)
+        return out
 
     # -- worker loops -----------------------------------------------------
 
@@ -354,15 +374,21 @@ class DeviceExecutor:
                     current = None
                     continue
                 t_build = time.perf_counter()
+                sp = spans.tracer()
+                bid = pb.dispatch_id
                 try:
                     if self._fault_hook is not None:
                         self._fault_hook("dispatch", self, pb)
-                    g = self._build_fn(pb)
-                    run = self._program_fn(self, pb.bucket, g)
-                    # one snapshot pins this batch to its dispatch-time
-                    # params version (hot reload swaps the pair atomically)
-                    params, pver = self._params_v
-                    out = run(params, g)        # asynchronous dispatch
+                    with sp(spans.BUILD, batch=bid):
+                        g = self._build_fn(pb)
+                    with sp(spans.LAUNCH, batch=bid):
+                        run = self._program_fn(self, pb.bucket, g)
+                        # one snapshot pins this batch to its dispatch-time
+                        # params version (hot reload swaps the pair
+                        # atomically)
+                        params, pver = self._params_v
+                        # asynchronous dispatch
+                        out = self._call(run, pb.bucket, params, g)
                 except Exception as exc:        # bad batch: report, stay up
                     t = time.perf_counter()
                     self._finish(CompletedBatch(
@@ -378,15 +404,17 @@ class DeviceExecutor:
                 inflight = _InFlight(queue_name, pb, out, t_build,
                                      time.perf_counter(),
                                      params_version=pver)
-                while True:
-                    if self._dead:
-                        self._fail_batch(queue_name, pb, self._dead_exc())
-                        break
-                    try:
-                        self._staging.put(inflight, timeout=0.2)
-                        break
-                    except queue.Full:
-                        continue
+                with sp(spans.STAGE, batch=bid):
+                    while True:
+                        if self._dead:
+                            self._fail_batch(queue_name, pb,
+                                             self._dead_exc())
+                            break
+                        try:
+                            self._staging.put(inflight, timeout=0.2)
+                            break
+                        except queue.Full:
+                            continue
                 current = None
         except BaseException as exc:
             self._loop_fatal(exc, current)
@@ -403,11 +431,17 @@ class DeviceExecutor:
                 current = (item.queue, item.batch)
                 err: Optional[Exception] = None
                 results: Optional[List[np.ndarray]] = None
+                sp = spans.tracer()
+                bid = item.batch.dispatch_id
                 try:
                     if self._fault_hook is not None:
                         self._fault_hook("complete", self, item.batch)
-                    out_np = np.asarray(jax.block_until_ready(item.out))
-                    results = self._unpack_fn(item.batch, out_np)
+                    with sp(spans.DEVICE_WAIT, batch=bid):
+                        ready = jax.block_until_ready(item.out)
+                    with sp(spans.FETCH, batch=bid):
+                        out_np = np.asarray(ready)
+                    with sp(spans.UNPACK, batch=bid):
+                        results = self._unpack_fn(item.batch, out_np)
                 except Exception as exc:
                     err = exc
                 t_ready = time.perf_counter()

@@ -69,7 +69,7 @@ from repro.core.errors import (BatchFailed, DeadlineExceeded, EngineClosed,
                                PoisonGraph, UnknownQueue)
 from repro.core.executor import CompletedBatch, DeviceExecutor
 from repro.core.faults import FaultInjector
-from repro.core.graph import GraphBatch, build_graph_batch, pad_bucket
+from repro.core.graph import FlatLayout, pad_bucket
 from repro.core.message_passing import (DEFAULT_DATAFLOW, DataflowConfig,
                                         count_edge_passes)
 from repro.core.models import GNNConfig, make_gnn
@@ -125,6 +125,10 @@ class StreamStats:
     circuit breaker's demotions and cooldown re-probes, and
     ``param_updates``/``param_rollbacks`` count hot parameter reloads
     promoted vs rejected (canary failure / incompatible tree).
+
+    Transfer accounting (DESIGN.md §5): ``h2d_transfers``/``h2d_bytes``
+    count the host→device puts of dispatched batches — one per batch, of
+    its ``FlatLayout`` buffer.
     """
 
     latencies_s: List[float] = field(default_factory=list)
@@ -153,6 +157,8 @@ class StreamStats:
     breaker_probes: int = 0
     param_updates: int = 0
     param_rollbacks: int = 0
+    h2d_transfers: int = 0
+    h2d_bytes: int = 0
 
     def record_batch(self, *, latencies: Sequence[float],
                      queue_waits: Sequence[float], device_s: float,
@@ -248,6 +254,9 @@ class StreamStats:
             out["aggregate_gps"] = float(
                 sum(self.batch_sizes)
                 / (self.t_last_done - self.t_first_dispatch))
+        if self.h2d_transfers:
+            out["h2d_transfers"] = int(self.h2d_transfers)
+            out["h2d_bytes"] = int(self.h2d_bytes)
         self._failure_summary(out)
         self._load_summary(out)
         self._defense_summary(out)
@@ -539,6 +548,8 @@ class GraphStreamEngine:
         self._params_by_version: Dict[int, Any] = {0: params}
         self._update_lock = threading.Lock()
         self._canary_run = None        # lazily-jitted default-df program
+        self._layouts: Dict[BucketKey, FlatLayout] = {}
+        self._h2d_lock = threading.Lock()     # dispatch threads count puts
 
         # executor pool: one per device, params committed per device
         self._devices = (list(devices) if devices is not None
@@ -922,12 +933,9 @@ class GraphStreamEngine:
         for node_pad, edge_pad in pairs:
             for graph_pad in self._scheduler.graph_pads():
                 key = (node_pad, edge_pad, graph_pad)
+                flat = self._synthetic_batch(node_pad, edge_pad, graph_pad)
                 for ex in self._executors:
-                    # fresh batch per executor: the compiled program
-                    # donates its graph argument off-CPU, so a shared
-                    # batch would hand executor 2 deleted buffers
-                    ex.warm(key, self._synthetic_batch(node_pad, edge_pad,
-                                                       graph_pad))
+                    ex.warm(key, jax.device_put(flat, ex.device))
                 keys.append(key)
         return keys
 
@@ -1559,7 +1567,7 @@ class GraphStreamEngine:
     def _make_executor(self, device, index: int, params) -> DeviceExecutor:
         ex = DeviceExecutor(
             device=device, index=index, params=params,
-            build_fn=self._build_batch,
+            build_fn=lambda pb: self._to_device(pb, device),
             program_fn=self._ensure_program,
             unpack_fn=self._unpack,
             on_complete=self._handle_completion,
@@ -1570,8 +1578,27 @@ class GraphStreamEngine:
         ex.set_params(params, self._param_version)
         return ex
 
-    def _build_batch(self, pb: PackedBatch) -> GraphBatch:
-        return pb.build(pos_dim=self.cfg.pos_dim)
+    def _layout(self, key: BucketKey) -> FlatLayout:
+        layout = self._layouts.get(key)
+        if layout is None:
+            c = self.cfg
+            layout = self._layouts.setdefault(key, FlatLayout(
+                *key, c.node_feat_dim, c.edge_feat_dim, c.pos_dim))
+        return layout
+
+    def _build_batch(self, pb: PackedBatch) -> np.ndarray:
+        """The batch packed into its bucket's flat host buffer."""
+        return self._layout(pb.bucket).pack(pb.items)
+
+    def _to_device(self, pb: PackedBatch, device) -> jax.Array:
+        """The served program's input: one host→device transfer per
+        batch, committed to the executor's own device."""
+        words = self._build_batch(pb)
+        flat = jax.device_put(words, device)
+        with self._h2d_lock:
+            self.stats.h2d_transfers += 1
+            self.stats.h2d_bytes += words.nbytes
+        return flat
 
     def _handle_completion(self, ex: DeviceExecutor,
                            done: CompletedBatch) -> None:
@@ -1996,22 +2023,24 @@ class GraphStreamEngine:
         must be finite and allclose to the jnp mirror's answer under the
         SAME new params — a swap that would corrupt results is caught
         before any real traffic can see it."""
-        g = self._probe_batch()
+        pb = self._probe_batch()
+        flat = self._build_batch(pb)
         try:
-            ref = np.asarray(self._audit_reference()(new_params, g))
+            ref = np.asarray(self._audit_reference()(
+                new_params, pb.build(pos_dim=self.cfg.pos_dim)))
         except Exception as exc:
             return f"reference eval failed: {exc}"
         if not bool(np.all(np.isfinite(ref))):
             return "jnp-mirror outputs are non-finite under new params"
         run = self._canary_run
         if run is None:
-            # default-dataflow probe program, compiled once per engine;
-            # donate=False — the probe batch is reused across executors
-            run = self._make_run(self.dataflow, donate=False)
+            # default-dataflow probe program, compiled once per engine
+            run = self._make_run(self.dataflow, pb.bucket)
             self._canary_run = run
         for ex, rep in zip(alive, replicas):
             try:
-                out = np.asarray(jax.block_until_ready(run(rep, g)))
+                out = np.asarray(jax.block_until_ready(
+                    run(rep, jax.device_put(flat, ex.device))))
             except Exception as exc:
                 return f"canary batch failed on {ex.label}: {exc}"
             if not bool(np.all(np.isfinite(out))):
@@ -2021,7 +2050,7 @@ class GraphStreamEngine:
                 return f"canary diverges from jnp mirror on {ex.label}"
         return None
 
-    def _probe_batch(self) -> GraphBatch:
+    def _probe_batch(self) -> PackedBatch:
         """A small deterministic ring graph with non-trivial features in
         the smallest bucket — rich enough that wrong params actually move
         its outputs (an all-zeros batch would pass any canary)."""
@@ -2035,10 +2064,11 @@ class GraphStreamEngine:
         ef = (rng.standard_normal(
             (n, self.cfg.edge_feat_dim)).astype(np.float32)
             if self.cfg.edge_feat_dim != 1 else None)
-        return build_graph_batch(
-            nf, snd, rcv, edge_feat=ef, node_pad=b0,
-            edge_pad=pad_bucket(2 * b0, self.buckets), graph_pad=1,
-            pos_dim=self.cfg.pos_dim)
+        return PackedBatch(
+            items=[PackItem(node_feat=nf, senders=snd, receivers=rcv,
+                            edge_feat=ef)],
+            node_pad=b0, edge_pad=pad_bucket(2 * b0, self.buckets),
+            graph_pad=1)
 
     # ------------------------------------------------------------------
     # drift detection -> bounded re-autotune (DESIGN.md §5)
@@ -2209,25 +2239,22 @@ class GraphStreamEngine:
     # per-executor program cache + shared per-bucket autotuning
     # ------------------------------------------------------------------
 
-    def _make_run(self, df: DataflowConfig, donate: bool = True):
+    def _make_run(self, df: DataflowConfig, key: BucketKey):
+        """The bucket's program: ``run(params, flat)`` on the batch's
+        ``FlatLayout`` buffer, rebuilt into a ``GraphBatch`` in-program."""
         apply = self.model.apply
         cfg = self.cfg
-        # donating the GraphBatch lets the runtime reuse its buffers for the
-        # outputs; CPU ignores donation (and warns), so gate on backend.
-        # Autotune timing runs pass donate=False: they reuse one batch
-        # across candidates (and the winner's real dispatch), so its buffers
-        # must survive every timing call.
-        argnums = (1,) if donate and jax.default_backend() != "cpu" else ()
+        layout = self._layout(key)
 
         # a stable name: the program's modules read ``jit_flowgnn_forward``
         # on the device planes of a profiler trace
-        def flowgnn_forward(params, graph):
-            return apply(params, graph, cfg, df)
+        def flowgnn_forward(params, flat):
+            return apply(params, layout.unflatten(flat), cfg, df)
 
-        return jax.jit(flowgnn_forward, donate_argnums=argnums)
+        return jax.jit(flowgnn_forward)
 
     def _ensure_program(self, ex: DeviceExecutor, key: BucketKey,
-                        g: GraphBatch):
+                        g: jax.Array):
         """The jitted program for ``key`` on executor ``ex``.
 
         The tuned dataflow is shared across the pool (first executor to
@@ -2248,7 +2275,7 @@ class GraphStreamEngine:
             return self._ensure_program_locked(ex, key, g)
 
     def _ensure_program_locked(self, ex: DeviceExecutor, key: BucketKey,
-                               g: GraphBatch):
+                               g: jax.Array):
         """``_ensure_program``'s miss path: tune, trace and install the
         bucket's program under the compile lock."""
         with self._compile_lock:
@@ -2267,7 +2294,7 @@ class GraphStreamEngine:
             # never left unservable by a broken lowering.
             while True:
                 eff = self._effective_df(key, df)
-                run = self._make_run(eff)
+                run = self._make_run(eff, key)
                 try:
                     with count_edge_passes() as ps:
                         jax.eval_shape(run, ex.params, g)
@@ -2369,7 +2396,7 @@ class GraphStreamEngine:
         return cands[:self._max_autotune]
 
     def _run_autotune(self, ex: DeviceExecutor, key: BucketKey,
-                      g: GraphBatch) -> DataflowConfig:
+                      g: jax.Array) -> DataflowConfig:
         """Time up to ``max_autotune`` (num_banks, edge_tile, impl) DSE
         candidates on the first batch of this bucket (on the executor that
         received it); cache and persist the winner for the whole pool."""
@@ -2380,7 +2407,7 @@ class GraphStreamEngine:
             name = f"banks{df.num_banks}_tile{df.edge_tile}"
             if df.impl != self.dataflow.impl:
                 name += f"_{df.impl}"
-            run = self._make_run(df, donate=False)
+            run = self._make_run(df, key)
             try:
                 jax.block_until_ready(run(ex.params, g))   # compile
                 t = min(self._time_once(run, ex.params, g) for _ in range(3))
@@ -2415,7 +2442,7 @@ class GraphStreamEngine:
         self._save_autotune_cache()
         return best_df
 
-    def _time_once(self, run, params, g: GraphBatch) -> float:
+    def _time_once(self, run, params, g: jax.Array) -> float:
         t0 = time.perf_counter()
         jax.block_until_ready(run(params, g))
         return time.perf_counter() - t0
@@ -2514,14 +2541,15 @@ class GraphStreamEngine:
     # ------------------------------------------------------------------
 
     def _synthetic_batch(self, node_pad: int, edge_pad: int,
-                         graph_pad: int) -> GraphBatch:
-        """Minimal real content padded to a bucket (for warmup/compile)."""
+                         graph_pad: int) -> np.ndarray:
+        """Minimal real content in a bucket's flat host buffer (for
+        warmup/compile)."""
         nf = np.zeros((2, self.cfg.node_feat_dim), np.float32)
         snd = np.array([0], np.int32)
         rcv = np.array([1], np.int32)
         ef = (np.zeros((1, self.cfg.edge_feat_dim), np.float32)
               if self.cfg.edge_feat_dim != 1 else None)
-        return build_graph_batch(
-            nf, snd, rcv, edge_feat=ef, node_pad=node_pad,
-            edge_pad=edge_pad, graph_pad=graph_pad,
-            pos_dim=self.cfg.pos_dim)
+        return self._build_batch(PackedBatch(
+            items=[PackItem(node_feat=nf, senders=snd, receivers=rcv,
+                            edge_feat=ef)],
+            node_pad=node_pad, edge_pad=edge_pad, graph_pad=graph_pad))

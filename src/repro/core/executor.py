@@ -12,8 +12,9 @@ pipelines (DESIGN.md §5).
 The executor knows nothing about queues, futures, stats, or autotuning:
 the engine injects
 
-  * ``build_fn(pb)``                 — PackedBatch -> padded GraphBatch
-    (host numpy work, runs on this executor's dispatch thread),
+  * ``build_fn(pb)``                 — PackedBatch -> the program's
+    input on this executor's device (host packing plus one transfer,
+    runs on this executor's dispatch thread),
   * ``program_fn(ex, key, graph)``   — returns the jitted program for a
     bucket on THIS executor (the engine's compile/autotune cache,
     namespaced per device),
@@ -364,6 +365,9 @@ class DeviceExecutor:
         current: Optional[Tuple[str, PackedBatch]] = None
         try:
             while True:
+                # drop the last batch before waiting for the next: its
+                # requests must not stay alive while this thread idles
+                item = pb = g = out = inflight = None
                 item = self._inbox.get()
                 if item is _SENTINEL:
                     return
@@ -425,6 +429,7 @@ class DeviceExecutor:
         current: Optional[Tuple[str, PackedBatch]] = None
         try:
             while True:
+                item = results = ready = out_np = None      # as above
                 item = self._staging.get()
                 if item is _SENTINEL:
                     return

@@ -14,13 +14,19 @@ Padding convention:
   * multiple small graphs are packed into one batch; ``graph_ids`` maps each
     node to its graph for segment pooling (the paper streams graphs at batch
     size 1; batching here is the same packing used for its Fig. 7 sweep).
+
+The serving path sends a batch to its device as ONE int32 buffer
+(``FlatLayout``): ``pack`` fills it on the host, ``unflatten`` rebuilds the
+same ``GraphBatch`` inside the jitted program. ``padding_fields`` is the one
+definition of the masks and pooling ids both forms derive from the counts.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
-from typing import Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -86,9 +92,10 @@ def build_graph_batch(
     """
     n, f = node_feat.shape
     e = senders.shape[0]
-    if n > node_pad or e > edge_pad:
-        raise ValueError(f"graph ({n} nodes, {e} edges) exceeds padding "
-                         f"({node_pad}, {edge_pad})")
+    if graph_offsets is None:
+        graph_offsets = np.array([0, n])
+    n_graphs = len(graph_offsets) - 1
+    _check_fits(n, e, n_graphs, node_pad, edge_pad, graph_pad)
     if edge_feat is None:
         edge_feat = np.zeros((e, 1), dtype=np.float32)
     d = edge_feat.shape[1]
@@ -106,21 +113,10 @@ def build_graph_batch(
     npos = np.zeros((node_pad, node_pos.shape[1]), dtype=np.float32)
     npos[:n] = node_pos
 
-    nmask = np.arange(node_pad) < n
-    emask = np.arange(edge_pad) < e
-
-    gids = np.zeros((node_pad,), dtype=np.int32)
-    if graph_offsets is None:
-        graph_offsets = np.array([0, n])
-    n_graphs = len(graph_offsets) - 1
-    if n_graphs > graph_pad:
-        raise ValueError(f"{n_graphs} graphs exceed graph_pad={graph_pad}")
-    for g in range(n_graphs):
-        gids[graph_offsets[g]:graph_offsets[g + 1]] = g
-    # padded nodes pool into the last (masked) graph slot if it exists, else 0;
-    # they are masked out of pooling anyway via node_mask.
-    gids[n:] = min(n_graphs, graph_pad - 1)
-    gmask = np.arange(graph_pad) < n_graphs
+    ends = np.full((graph_pad,), n, dtype=np.int32)
+    ends[:n_graphs] = graph_offsets[1:]
+    nmask, emask, gids, gmask = padding_fields(
+        np, n, e, n_graphs, ends, node_pad, edge_pad, graph_pad)
 
     return GraphBatch(
         node_feat=jnp.asarray(nf),
@@ -133,6 +129,153 @@ def build_graph_batch(
         graph_mask=jnp.asarray(gmask),
         node_pos=jnp.asarray(npos),
     )
+
+
+def _check_fits(n: int, e: int, n_graphs: int, node_pad: int, edge_pad: int,
+                graph_pad: int) -> None:
+    if n > node_pad or e > edge_pad:
+        raise ValueError(f"graph ({n} nodes, {e} edges) exceeds padding "
+                         f"({node_pad}, {edge_pad})")
+    if n_graphs > graph_pad:
+        raise ValueError(f"{n_graphs} graphs exceed graph_pad={graph_pad}")
+
+
+def padding_fields(xp, n, e, n_graphs, ends, node_pad: int, edge_pad: int,
+                   graph_pad: int):
+    """``(node_mask, edge_mask, graph_ids, graph_mask)`` of a padded batch.
+
+    ``xp`` is numpy (host build) or jax.numpy (inside the program); ``n``,
+    ``e`` and ``n_graphs`` are the real node, edge and graph counts and
+    ``ends`` (``graph_pad`` entries) the node index where each graph ends,
+    ``n`` past the last real graph. Masks are ``iota < count``. A real
+    node's graph is the number of graphs that end at or before it; padded
+    nodes pool into the last (masked) graph slot if it exists, else 0 —
+    they are masked out of pooling anyway via ``node_mask``.
+    """
+    node = xp.arange(node_pad, dtype=xp.int32)
+    gids = xp.sum(ends[:, None] <= node[None, :], axis=0, dtype=xp.int32)
+    gids = xp.where(node < n, gids, xp.minimum(n_graphs, graph_pad - 1))
+    return (node < n, xp.arange(edge_pad) < e, gids.astype(xp.int32),
+            xp.arange(graph_pad) < n_graphs)
+
+
+@dataclass(frozen=True)
+class FlatLayout:
+    """Where each field of one padded batch lies in a single int32 buffer.
+
+    A pure function of the bucket and the model's input widths, so one
+    compiled program per bucket reads every batch packed for it. In
+    order: the node, edge and graph counts; ``ends`` (``graph_pad``
+    words, see ``padding_fields``); node features, node positions, edge
+    features (float32, stored bit for bit); senders; receivers. Masks and
+    ``graph_ids`` are not sent: ``unflatten`` derives them from the
+    counts by the same rules ``build_graph_batch`` uses, so the rebuilt
+    ``GraphBatch`` holds the same bits as the host-built one.
+    """
+
+    node_pad: int
+    edge_pad: int
+    graph_pad: int
+    node_feat_dim: int
+    edge_feat_dim: int
+    pos_dim: int
+
+    _FLOAT = ("node_feat", "node_pos", "edge_feat")
+
+    @functools.cached_property
+    def fields(self) -> Dict[str, Tuple[int, int, Tuple[int, ...]]]:
+        """name -> (first word, end word, shape)."""
+        n, e = self.node_pad, self.edge_pad
+        shapes = (("counts", (3,)), ("ends", (self.graph_pad,)),
+                  ("node_feat", (n, self.node_feat_dim)),
+                  ("node_pos", (n, self.pos_dim)),
+                  ("edge_feat", (e, self.edge_feat_dim)),
+                  ("senders", (e,)), ("receivers", (e,)))
+        out, at = {}, 0
+        for name, shape in shapes:
+            size = int(np.prod(shape))
+            out[name] = (at, at + size, shape)
+            at += size
+        return out
+
+    @property
+    def size(self) -> int:
+        """Length of the buffer in int32 words."""
+        return self.fields["receivers"][1]
+
+    def _view(self, buf, name: str):
+        a, b, shape = self.fields[name]
+        return buf[a:b].reshape(shape)
+
+    def pack(self, graphs: Sequence) -> np.ndarray:
+        """The padded batch of ``graphs`` in one new int32 buffer (host).
+
+        ``graphs`` are objects with ``node_feat / senders / receivers`` and
+        optional ``edge_feat / node_pos``, packed in order with edge indices
+        shifted by each graph's node offset; a graph without an optional
+        field gets zeros, as in ``concat_raw_graphs``. A field of another
+        width than the layout's raises, with one exception: where
+        ``edge_feat_dim`` is 1 the model takes no edge features, admission
+        lets any width through, and an ``edge_feat`` of another width is
+        left out. The buffer is new on every call: a device put of it is
+        asynchronous, so it must not be written again once handed over.
+        """
+        nodes = np.array([g.node_feat.shape[0] for g in graphs], np.int32)
+        edges = np.array([g.senders.shape[0] for g in graphs], np.int64)
+        n, e, n_graphs = int(nodes.sum()), int(edges.sum()), len(graphs)
+        _check_fits(n, e, n_graphs, self.node_pad, self.edge_pad,
+                    self.graph_pad)
+        words = np.zeros(self.size, np.int32)
+        floats = words.view(np.float32)
+        words[:3] = n, e, n_graphs
+        ends = self._view(words, "ends")
+        np.cumsum(nodes, out=ends[:n_graphs])
+        ends[n_graphs:] = n
+        _put_rows(self._view(floats, "node_feat"),
+                  [g.node_feat for g in graphs], nodes)
+        _put_rows(self._view(floats, "node_pos"),
+                  [getattr(g, "node_pos", None) for g in graphs], nodes)
+        efs = [getattr(g, "edge_feat", None) for g in graphs]
+        if self.edge_feat_dim == 1:
+            efs = [ef if ef is not None and ef.shape[1] == 1 else None
+                   for ef in efs]
+        _put_rows(self._view(floats, "edge_feat"), efs, edges)
+        shift = np.repeat(ends[:n_graphs] - nodes, edges)
+        for name in ("senders", "receivers"):
+            idx = self._view(words, name)[:e]
+            np.concatenate([getattr(g, name) for g in graphs], out=idx,
+                           casting="unsafe")
+            idx += shift
+        return words
+
+    def unflatten(self, words) -> GraphBatch:
+        """The ``GraphBatch`` ``pack`` padded, rebuilt from its buffer
+        inside the jitted program: static slices and bitcasts."""
+        def field(name):
+            x = self._view(words, name)
+            if name in self._FLOAT:
+                x = jax.lax.bitcast_convert_type(x, jnp.float32)
+            return x
+
+        node_mask, edge_mask, graph_ids, graph_mask = padding_fields(
+            jnp, words[0], words[1], words[2], field("ends"), self.node_pad,
+            self.edge_pad, self.graph_pad)
+        return GraphBatch(
+            node_feat=field("node_feat"), edge_feat=field("edge_feat"),
+            senders=field("senders"), receivers=field("receivers"),
+            node_mask=node_mask, edge_mask=edge_mask, graph_ids=graph_ids,
+            graph_mask=graph_mask, node_pos=field("node_pos"))
+
+
+def _put_rows(dst: np.ndarray, arrays, rows: np.ndarray) -> None:
+    """Each graph's rows of one field, in order, into the top of ``dst``;
+    a graph without the field (None) gets zero rows. Shapes must match
+    ``dst`` exactly: a field of another width raises, nothing broadcasts."""
+    if all(a is None for a in arrays):
+        return                                  # the buffer is zero already
+    np.concatenate([np.zeros((r, dst.shape[1]), dst.dtype) if a is None
+                    else a for a, r in zip(arrays, rows)],
+                   out=dst[:int(rows.sum())], casting="unsafe")
 
 
 def concat_raw_graphs(graphs) -> dict:

@@ -145,7 +145,9 @@ class PackedBatch:
         return self.subset(self.items[:mid]), self.subset(self.items[mid:])
 
     def build(self, *, pos_dim: int = 1) -> GraphBatch:
-        """Concatenate + pad into a device-ready ``GraphBatch`` (numpy work)."""
+        """Concatenate + pad into a ``GraphBatch`` (numpy work). The
+        serving path packs with ``FlatLayout.pack`` instead; this form
+        feeds the shadow auditor's mirror and the references."""
         raw = concat_raw_graphs(self.items)
         return build_graph_batch(
             raw["node_feat"], raw["senders"], raw["receivers"],

@@ -164,9 +164,9 @@ def test_autotune_report_names_failed_candidates(monkeypatch):
     with _make_engine("gin", max_batch=1, autotune=True) as eng:
         make_run = eng._make_run
 
-        def refusing(df, donate=True):
+        def refusing(df, key):
             if df.impl != "pipeline":
-                return make_run(df, donate)
+                return make_run(df, key)
 
             def run(params, graph):
                 raise RuntimeError("kernel refused by the compiler")

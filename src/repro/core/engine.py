@@ -75,7 +75,7 @@ from repro.core.message_passing import (DEFAULT_DATAFLOW, DataflowConfig,
 from repro.core.models import GNNConfig, make_gnn
 from repro.core.packing import PackedBatch, PackItem
 from repro.core.scheduler import BatchScheduler, QueueConfig
-from repro.core.validate import check_budget, check_graph
+from repro.core.validate import check_budget, check_graph, check_graph_nodes
 from repro.distributed.sharding import (device_kind, params_compatible,
                                         replicate_params)
 from repro.distributed.wide import (WidePlan, WidePlanError, build_wide_forward,
@@ -727,10 +727,17 @@ class GraphStreamEngine:
                 with self._cv:
                     self.stats.invalid_rejects += 1
                 raise InvalidGraph(reason, request_ids=(req_id,))
-        # single-device budget gate (DESIGN.md §10): a graph no bucket can
-        # hold is servable only by splitting it across a gang of executors
         n_nodes = int(np.asarray(node_feat).shape[0])
         n_edges = int(np.asarray(senders).shape[0])
+        # the model's own per-graph limit (GPS's attention slot): checked
+        # whatever validate_inputs says, as nothing downstream can serve it
+        reason = check_graph_nodes(n_nodes, self.cfg.attention_slot)
+        if reason is not None:
+            with self._cv:
+                self.stats.invalid_rejects += 1
+            raise InvalidGraph(reason, request_ids=(req_id,))
+        # single-device budget gate (DESIGN.md §10): a graph no bucket can
+        # hold is servable only by splitting it across a gang of executors
         node_budget = max(self.buckets)
         wide_plan: Optional[WidePlan] = None
         if n_nodes > node_budget:

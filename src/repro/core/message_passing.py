@@ -60,7 +60,7 @@ from __future__ import annotations
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Callable, Dict, Optional, Sequence, Union
+from typing import Callable, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -937,7 +937,8 @@ def propagate(
     stats: Optional[PrecomputedGraphStats] = None,
     fusable: Optional[FusableMessage] = None,
     fusable_update: Optional[FusableUpdate] = None,
-) -> Array:
+    edge_output: bool = False,
+) -> Union[Array, Tuple[Array, Array]]:
     """One message-passing layer.
 
     message_fn(x_src, x_dst, e)  -> (E, D)      # phi — scatter phase
@@ -972,8 +973,17 @@ def propagate(
     jnp region (via ``update_fn``, bitwise-equal to the unfused path) on
     the mirror. Layers with only a fusable phi keep the pipeline edge
     phase; layers with neither fall back to the unfused path.
+
+    ``edge_output=True`` is for layers that carry an edge state: then
+    ``message_fn`` returns ``(msg, edge_out)`` and ``propagate`` returns
+    ``(node_out, edge_out)``, so the per-edge value the message phase
+    computed (GatedGCN's pre-activation) comes back from the same sweep
+    instead of being recomputed. Such a layer has no fusable form.
     """
     kinds = (aggregate,) if isinstance(aggregate, str) else tuple(aggregate)
+    if edge_output and fusable is not None:
+        raise ValueError("edge_output has no fusable form: pass "
+                         "fusable=None")
     if dataflow.impl in ("pipeline", "fused_layer") and fusable is not None:
         fu = fusable_update
         if (dataflow.impl == "fused_layer" and fu is not None
@@ -1053,6 +1063,8 @@ def propagate(
     src = jnp.take(x, graph.senders, axis=0)
     dst = jnp.take(x, graph.receivers, axis=0)
     msg = message_fn(src, dst, ef)
+    if edge_output:
+        msg, edge_out = msg
     _count_pass()                 # the gather + phi (E, D) message rewrite
 
     if dataflow.impl == "twopass":
@@ -1081,7 +1093,8 @@ def propagate(
         ]
         m = jnp.concatenate(aggs, axis=-1)
     out = update_fn(x, m)
-    return jnp.where(graph.node_mask[:, None], out, 0.0)
+    out = jnp.where(graph.node_mask[:, None], out, 0.0)
+    return (out, edge_out) if edge_output else out
 
 
 def global_pool(graph: GraphBatch, x: Array, *, kind: str = "mean",

@@ -1,4 +1,5 @@
-"""The FlowGNN model zoo: GCN, GIN, GIN+VN, GAT, PNA, DGN (paper Table II).
+"""The FlowGNN model zoo: GCN, GIN, GIN+VN, GAT, PNA, DGN (paper Table II),
+and the graph transformer GraphGPS (arXiv:2205.12454).
 
 Each model is a functional (init, apply) pair built on the generic
 message-passing engine. Layer counts / dims default to the paper's Sec. VI-A
@@ -63,8 +64,12 @@ class GNNConfig:
     node_feat_dim: int = 9          # OGB-mol style raw features
     edge_feat_dim: int = 3
     out_dim: int = 1
-    heads: int = 4                  # GAT
-    head_dim: int = 16              # GAT
+    heads: int = 4                  # GAT, GPS attention
+    head_dim: int = 16              # GAT, GPS attention
+    ffn_dim: int = 768              # GPS feed-forward width
+    pe_steps: int = 16              # GPS RWSE: random-walk steps
+    pe_dim: int = 20                # GPS RWSE: encoded width
+    attention_slot: Optional[int] = None  # GPS: most nodes one graph may have
     pos_dim: int = 1                # DGN directional field width
     avg_log_degree: float = 1.3     # PNA's delta (from "training set")
     task: str = "graph"             # graph | node
@@ -661,6 +666,240 @@ def dgn_apply(params, graph: GraphBatch, cfg: GNNConfig,
 
 
 # ---------------------------------------------------------------------------
+# GraphGPS — GatedGCN local block + per-graph Transformer, RWSE positions
+# (Rampášek et al., arXiv:2205.12454; DESIGN.md §13)
+# ---------------------------------------------------------------------------
+
+_BN_EPS = 1e-5      # torch.nn.BatchNorm1d's default
+
+
+def _bn_init(key, d: int, dtype=jnp.float32) -> Params:
+    """Inference BatchNorm: scale, shift and running statistics, seeded
+    away from the identity so that folding them is exercised."""
+    kg, kb, km, kv = jax.random.split(key, 4)
+    return {"gamma": 1.0 + 0.1 * jax.random.normal(kg, (d,), dtype),
+            "beta": 0.1 * jax.random.normal(kb, (d,), dtype),
+            "mean": 0.1 * jax.random.normal(km, (d,), dtype),
+            "var": jax.random.uniform(kv, (d,), dtype, 0.5, 1.5)}
+
+
+def _bn(p: Params, x: Array) -> Array:
+    """Inference BatchNorm folded into one affine map at trace time."""
+    scale = p["gamma"] * jax.lax.rsqrt(p["var"] + _BN_EPS)
+    return x * scale + (p["beta"] - p["mean"] * scale)
+
+
+def gps_init(key, cfg: GNNConfig) -> Params:
+    d, f = cfg.hidden_dim, cfg.ffn_dim
+    if cfg.attention_slot is None:
+        raise ValueError("gps needs attention_slot: the most nodes one "
+                         "graph may have")
+    if cfg.heads * cfg.head_dim != d:
+        raise ValueError(f"gps needs heads * head_dim == hidden_dim, got "
+                         f"{cfg.heads} * {cfg.head_dim} != {d}")
+    if not 0 < cfg.pe_dim < d:
+        raise ValueError(f"gps needs 0 < pe_dim < hidden_dim, got "
+                         f"{cfg.pe_dim}")
+    dt = cfg.dtype
+    k_node, k_bn, k_pe, k_edge, k_layers, k_head = jax.random.split(key, 6)
+    layers = []
+    for k in jax.random.split(k_layers, cfg.num_layers):
+        ks = jax.random.split(k, 14)
+        layer = {name: _dense_init(kk, d, d, dt)
+                 for name, kk in zip("ABCDE", ks[:5])}
+        layer.update({
+            "bn_x": _bn_init(ks[5], d, dt),
+            "bn_e": _bn_init(ks[6], d, dt),
+            "norm_local": _bn_init(ks[7], d, dt),
+            "attn_in": _dense_init(ks[8], d, 3 * d, dt),
+            "attn_out": _dense_init(ks[9], d, d, dt),
+            "norm_attn": _bn_init(ks[10], d, dt),
+            "ffn1": _dense_init(ks[11], d, f, dt),
+            "ffn2": _dense_init(ks[12], f, d, dt),
+            "norm_ffn": _bn_init(ks[13], d, dt),
+        })
+        layers.append(layer)
+    return {
+        "node_enc": _dense_init(k_node, cfg.node_feat_dim, d - cfg.pe_dim, dt),
+        "pe_norm": _bn_init(k_bn, cfg.pe_steps, dt),
+        "pe_enc": _dense_init(k_pe, cfg.pe_steps, cfg.pe_dim, dt),
+        "edge_enc": _dense_init(k_edge, cfg.edge_feat_dim, d, dt),
+        "layers": layers,
+        "head": _head_init(k_head, cfg, d),
+    }
+
+
+class AttentionSlots(NamedTuple):
+    """Where each packed graph's nodes sit in a ``(G_pad, S)`` slot grid.
+
+    Graph ``g``'s nodes are contiguous in the batch, from
+    ``start[g] = Σ_{h<g} count[h]``; node ``start[g] + p`` sits in slot
+    ``(g, p)``. The counts are ``graph_node_counts``, the differences of
+    the ``ends`` the batch's ``FlatLayout`` carries.
+    """
+
+    rows: Array       # (G_pad * S,) node row read into each slot (clipped)
+    valid: Array      # (G_pad, S) the slot holds a node of its graph
+    back: Array       # (N_pad,) flat slot of each node (0 for padding)
+    pos: Array        # (N_pad,) node's place in its graph
+    fits: Array       # (N_pad,) padding, or a node inside its graph's slot
+
+
+def attention_slots(graph: GraphBatch, counts: Array, slot: int
+                    ) -> AttentionSlots:
+    n = graph.n_node_pad
+    cnt = counts.astype(jnp.int32)
+    start = jnp.cumsum(cnt) - cnt
+    p = jnp.arange(slot, dtype=jnp.int32)
+    rows = jnp.clip(start[:, None] + p[None, :], 0, n - 1).reshape(-1)
+    node = jnp.arange(n, dtype=jnp.int32)
+    pos = node - start[graph.graph_ids]
+    inside = graph.node_mask & (pos < slot)
+    back = jnp.where(inside, graph.graph_ids * slot + pos, 0)
+    return AttentionSlots(rows=rows, valid=p[None, :] < cnt[:, None],
+                          back=back, pos=pos,
+                          fits=inside | ~graph.node_mask)
+
+
+def random_walk_se(graph: GraphBatch, slots: AttentionSlots,
+                   steps: int) -> Array:
+    """RWSE: diag((D⁻¹A)^k), k = 1..steps, per node (N_pad, steps).
+
+    Built from the batch's own edges, per graph over its slot: ``A[s, r]``
+    counts the edges s → r and ``D`` is the out-degree, as GraphGPS's
+    ``get_rw_landing_probs``. A node with no out-edge has a zero row, so
+    its probabilities are 0. The products run at ``highest`` precision:
+    they are sums of probabilities, not a learned map.
+    """
+    g, s = slots.valid.shape
+    ps, pr = slots.pos[graph.senders], slots.pos[graph.receivers]
+    ok = graph.edge_mask & (ps < s) & (pr < s)
+    flat = (graph.graph_ids[graph.senders] * s + ps) * s + pr
+    _count_pass()                 # one scatter of the edge stream
+    a = jnp.zeros((g * s * s,), jnp.float32).at[
+        jnp.where(ok, flat, g * s * s)].add(1.0, mode="drop")
+    a = a.reshape(g, s, s)
+    deg = a.sum(-1, keepdims=True)
+    walk = a / jnp.where(deg > 0, deg, 1.0)
+
+    def power(m, _):
+        m = jnp.matmul(m, walk, precision=jax.lax.Precision.HIGHEST)
+        return m, jnp.diagonal(m, axis1=1, axis2=2)
+
+    _, diags = jax.lax.scan(power, jnp.broadcast_to(jnp.eye(s), walk.shape),
+                            None, length=steps)            # (steps, G, S)
+    pe = jnp.moveaxis(diags, 0, -1).reshape(g * s, steps)[slots.back]
+    return jnp.where(graph.node_mask[:, None], pe, 0.0)
+
+
+def slot_attention(p_in: Params, p_out: Params, x: Array,
+                   slots: AttentionSlots, heads: int) -> Array:
+    """Multi-head self-attention within each graph (GraphGPS's global
+    model). The Q/K/V and output maps run on the flat node rows; only
+    the scores and their weighted sum run in the (G_pad, S) slot grid,
+    with the keys of empty slots masked. The softmax is float32. A query
+    slot with no key (an empty graph slot) gets zero weights, so no NaN
+    is made; its row is never read back."""
+    d = x.shape[1]
+    g, s = slots.valid.shape
+    dh = d // heads
+    qkv = _dense(p_in, x)[slots.rows].reshape(g, s, 3, heads, dh)
+    q = qkv[:, :, 0] * (dh ** -0.5)
+    k, v = qkv[:, :, 1], qkv[:, :, 2]
+    scores = jnp.einsum("gqhd,gkhd->ghqk", q, k).astype(jnp.float32)
+    scores = jnp.where(slots.valid[:, None, None, :], scores, -jnp.inf)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    w = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-30)
+    out = jnp.einsum("ghqk,gkhd->gqhd", w.astype(x.dtype), v)
+    return _dense(p_out, out.reshape(g * s, d)[slots.back])
+
+
+def gps_layer(p, graph: GraphBatch, x: Array, e: Array,
+              slots: AttentionSlots, dataflow: DataflowConfig,
+              heads: int) -> Tuple[Array, Array]:
+    """One GPS layer: the GatedGCN local block and the attention read the
+    same input; their sum goes through the FFN.
+
+        ê_ij = D x_i + E x_j + C e_ij ;  σ_ij = sigmoid(ê_ij)
+        x̂_i  = A x_i + Σ_j σ_ij ⊙ B x_j / (Σ_j σ_ij + 1e-6)
+        x_L  = BN₁(x + relu(BN_x(x̂)))      e' = e + relu(BN_e(ê))
+        x_A  = BN₂(x + MHA(x within its graph))
+        h    = x_L + x_A ;  x' = BN₃(h + W₂ relu(W₁ h))
+    """
+    d = x.shape[1]
+    with jax.named_scope("gps.local"):
+        ax = _dense(p["A"], x)
+        ce = _dense(p["C"], e)
+
+        def message(src, dst, _e):
+            # rows are [B x | E x | D x]: each node mapped once, gathered;
+            # e_hat = D x_i + E x_j + C e_ij
+            e_hat = dst[:, 2 * d:] + src[:, d:2 * d] + ce
+            gate = jax.nn.sigmoid(e_hat)
+            # numerator and gate sum side by side: one segment sum
+            return jnp.concatenate([gate * src[:, :d], gate], -1), e_hat
+
+        def update(_rows, m):
+            return ax + m[:, :d] / (m[:, d:] + 1e-6)
+
+        rows = jnp.concatenate(
+            [_dense(p["B"], x), _dense(p["E"], x), _dense(p["D"], x)], -1)
+        x_hat, e_hat = propagate(
+            graph, rows, message_fn=message, update_fn=update,
+            aggregate="sum", dataflow=dataflow, edge_output=True)
+        x_local = _bn(p["norm_local"], x + jax.nn.relu(_bn(p["bn_x"], x_hat)))
+        e = jnp.where(graph.edge_mask[:, None],
+                      e + jax.nn.relu(_bn(p["bn_e"], e_hat)), 0.0)
+    with jax.named_scope("gps.attention"):
+        att = slot_attention(p["attn_in"], p["attn_out"], x, slots, heads)
+        # a node outside its graph's slot gets NaN, never a wrong answer
+        att = jnp.where(slots.fits[:, None], att, jnp.nan)
+        x_attn = _bn(p["norm_attn"], x + att)
+    with jax.named_scope("gps.ffn"):
+        h = x_local + x_attn
+        ff = _dense(p["ffn2"], jax.nn.relu(_dense(p["ffn1"], h)))
+        x = _bn(p["norm_ffn"], h + ff)
+    return jnp.where(graph.node_mask[:, None], x, 0.0), e
+
+
+def gps_apply(params, graph: GraphBatch, cfg: GNNConfig,
+              dataflow: DataflowConfig = DEFAULT_DATAFLOW,
+              stats: Optional[PrecomputedGraphStats] = None) -> Array:
+    """GraphGPS with RWSE positions computed in the forward from the
+    batch's edges (no graph pre-processing). Every graph must have at
+    most ``cfg.attention_slot`` nodes: the engine refuses larger ones at
+    ``submit``; here their answers come out NaN."""
+    if stats is None or stats.graph_node_counts is None:
+        stats = precompute_graph_stats(graph, with_degrees=False,
+                                       with_graph_counts=True)
+    slots = attention_slots(graph, stats.graph_node_counts,
+                            cfg.attention_slot)
+    with jax.named_scope("gps.rwse"):
+        pe = random_walk_se(graph, slots, cfg.pe_steps).astype(cfg.dtype)
+        pe = _dense(params["pe_enc"], _bn(params["pe_norm"], pe))
+    x = jnp.concatenate(
+        [_dense(params["node_enc"], graph.node_feat.astype(cfg.dtype)), pe],
+        -1)
+    x = jnp.where(graph.node_mask[:, None], x, 0.0)
+    e = jnp.where(graph.edge_mask[:, None],
+                  _dense(params["edge_enc"], graph.edge_feat.astype(cfg.dtype)),
+                  0.0)
+
+    def layer_step(xe, p):
+        return gps_layer(p, graph, *xe, slots, dataflow, cfg.heads)
+
+    if dataflow.scan_layers and cfg.num_layers > 1:
+        (x, e), _ = scan_layers(lambda xe, p: (layer_step(xe, p), None),
+                                (x, e), _stack_layers(params["layers"]),
+                                length=cfg.num_layers)
+    else:
+        for p in params["layers"]:
+            x, e = layer_step((x, e), p)
+    return _readout(params["head"], cfg, graph, x, stats)
+
+
+# ---------------------------------------------------------------------------
 # registry
 # ---------------------------------------------------------------------------
 
@@ -676,6 +915,7 @@ GNN_MODELS: Dict[str, GNNModel] = {
     "gat": GNNModel(gat_init, gat_apply),
     "pna": GNNModel(pna_init, pna_apply),
     "dgn": GNNModel(dgn_init, dgn_apply),
+    "gps": GNNModel(gps_init, gps_apply),
 }
 
 

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.graph import GraphBatch
-from repro.core.models import GNNConfig, _dense, _mlp
+from repro.core.models import _BN_EPS, GNNConfig, _dense, _mlp
 
 Array = jax.Array
 
@@ -170,6 +170,70 @@ def dgn_dense(params, graph: GraphBatch, cfg: GNNConfig) -> Array:
     return _readout(params["head"], cfg, graph, x)
 
 
+def _bn_explicit(p, x: Array) -> Array:
+    return (x - p["mean"]) / jnp.sqrt(p["var"] + _BN_EPS) * p["gamma"] \
+        + p["beta"]
+
+
+def gps_dense(params, graph: GraphBatch, cfg: GNNConfig) -> Array:
+    """GraphGPS (arXiv:2205.12454) with each graph alone: edges are an
+    (E, D) array whose endpoints are gathered by one-hot (E, N) matrices,
+    attention is dense over the nodes of the graph's own block, and RWSE
+    is diag((D⁻¹A)^k) by matrix powers of the (N, N) random walk.
+
+    Departures from GraphGPS: linear node and edge encoders in place of
+    the OGB atom and bond embedding tables; inference BatchNorm with its
+    running statistics, written out (the program folds it into one affine
+    map); no dropout. Duplicate edges count with their multiplicity.
+    """
+    hi = jax.lax.Precision.HIGHEST
+    n = graph.n_node_pad
+    w = graph.edge_mask.astype(jnp.float32)[:, None]
+    src = jax.nn.one_hot(graph.senders, n) * w               # (E, N)
+    dst = jax.nn.one_hot(graph.receivers, n) * w
+    a = jnp.matmul(src.T, dst, precision=hi)                  # a[s, r]: s -> r
+    deg = a.sum(1, keepdims=True)
+    walk = a / jnp.where(deg > 0, deg, 1.0)
+    m, steps = walk, []
+    for _ in range(cfg.pe_steps):
+        steps.append(jnp.diagonal(m))
+        m = jnp.matmul(m, walk, precision=hi)
+    pe = _dense(params["pe_enc"],
+                _bn_explicit(params["pe_norm"], jnp.stack(steps, -1)))
+    x = jnp.concatenate(
+        [_dense(params["node_enc"], graph.node_feat.astype(cfg.dtype)), pe],
+        -1)
+    x = _mask_nodes(graph, x)
+    e = _dense(params["edge_enc"], graph.edge_feat.astype(cfg.dtype)) * w
+    same = ((graph.graph_ids[:, None] == graph.graph_ids[None, :])
+            & graph.node_mask[None, :]) | jnp.eye(n, dtype=bool)
+    heads = cfg.heads
+    dh = cfg.hidden_dim // heads
+    for p in params["layers"]:
+        x_src = jnp.matmul(src, x, precision=hi)              # x_j per edge
+        x_dst = jnp.matmul(dst, x, precision=hi)              # x_i per edge
+        e_hat = _dense(p["D"], x_dst) + _dense(p["E"], x_src) \
+            + _dense(p["C"], e)
+        gate = jax.nn.sigmoid(e_hat) * w
+        num = jnp.matmul(dst.T, gate * _dense(p["B"], x_src), precision=hi)
+        den = jnp.matmul(dst.T, gate, precision=hi)
+        x_hat = _dense(p["A"], x) + num / (den + 1e-6)
+        x_local = _bn_explicit(
+            p["norm_local"], x + jax.nn.relu(_bn_explicit(p["bn_x"], x_hat)))
+        e = (e + jax.nn.relu(_bn_explicit(p["bn_e"], e_hat))) * w
+        q, k, v = jnp.split(_dense(p["attn_in"], x).reshape(n, 3, heads, dh),
+                            3, axis=1)
+        scores = jnp.einsum("qhd,khd->hqk", q[:, 0] / jnp.sqrt(dh), k[:, 0])
+        att = jax.nn.softmax(jnp.where(same[None], scores, -jnp.inf), -1)
+        mha = _dense(p["attn_out"],
+                     jnp.einsum("hqk,khd->qhd", att, v[:, 0]).reshape(n, -1))
+        x_attn = _bn_explicit(p["norm_attn"], x + mha)
+        h = x_local + x_attn
+        ff = _dense(p["ffn2"], jax.nn.relu(_dense(p["ffn1"], h)))
+        x = _mask_nodes(graph, _bn_explicit(p["norm_ffn"], h + ff))
+    return _readout(params["head"], cfg, graph, x)
+
+
 DENSE_REFS = {
     "gcn": gcn_dense,
     "gin": gin_dense,
@@ -177,4 +241,5 @@ DENSE_REFS = {
     "gat": gat_dense,
     "pna": pna_dense,
     "dgn": dgn_dense,
+    "gps": gps_dense,
 }

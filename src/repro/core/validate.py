@@ -134,6 +134,17 @@ def check_budget(num_nodes: int, num_edges: int, *,
     return None
 
 
+def check_graph_nodes(num_nodes: int, limit: Optional[int]) -> Optional[str]:
+    """Why this graph has more nodes than its model serves in one graph,
+    or ``None``. A model that attends within a slot of ``attention_slot``
+    rows per graph (GPS) refuses a larger graph rather than serve it
+    wrong; no bucket or wide split can hold it."""
+    if limit is not None and num_nodes > limit:
+        return (f"graph has {num_nodes} nodes > the model's limit of "
+                f"{limit} nodes per graph (attention_slot)")
+    return None
+
+
 def validate_graph(node_feat, senders, receivers, edge_feat=None,
                    node_pos=None, *, node_feat_dim: Optional[int] = None,
                    edge_feat_dim: Optional[int] = None,

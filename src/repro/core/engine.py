@@ -505,6 +505,17 @@ class GraphStreamEngine:
                                       else max_pending)
                             for qc in queue_cfgs}
         self._pending_by_queue = {qc.name: 0 for qc in queue_cfgs}
+        # what admission reads on every submit, read once here
+        self._default_queue = queue_cfgs[0].name
+        self._node_budget = max(buckets)
+        self._needs_edge_feat = cfg.edge_feat_dim != 1
+        # edge_feat_dim 1 means "model takes no edge features": any
+        # provided width is legal there (it is ignored), so the width
+        # check only binds when the model consumes edge features
+        self._graph_dims = dict(
+            node_feat_dim=cfg.node_feat_dim,
+            edge_feat_dim=cfg.edge_feat_dim if self._needs_edge_feat else None,
+            pos_dim=cfg.pos_dim)
 
         # failure-semantics knobs (DESIGN.md §8)
         if max_retries < 0:
@@ -617,7 +628,7 @@ class GraphStreamEngine:
         self._placer: Optional[threading.Thread] = None
 
         # failure-semantics state, all under self._cv:
-        self._req_seq = 0                         # next request id
+        self._req_ids = itertools.count()         # next request id
         self._requests: Dict[int, _Request] = {}  # outstanding futures
         self._retry_heap: List[Tuple[float, int, str, PackedBatch,
                                      Optional[int]]] = []
@@ -686,20 +697,19 @@ class GraphStreamEngine:
 
     def _submit(self, span, node_feat, senders, receivers, edge_feat,
                 node_pos, record, queue, deadline) -> Future:
-        if edge_feat is None and self.cfg.edge_feat_dim != 1:
+        if edge_feat is None and self._needs_edge_feat:
             raise InvalidRequest("model expects edge features")
         if deadline is not None and deadline <= 0:
             raise InvalidRequest("deadline must be > 0 seconds")
         if self._closed:        # don't spin up worker threads just to reject
             raise EngineClosed("engine is closed")
+        caps = self._queue_caps
         if queue is None:
-            queue = self._scheduler.queue_names[0]
-        elif queue not in self._scheduler.queue_names:
+            queue = self._default_queue
+        elif queue not in caps:
             raise UnknownQueue(f"unknown queue '{queue}'; "
-                               f"have {sorted(self._scheduler.queue_names)}")
-        with self._cv:
-            req_id = self._req_seq
-            self._req_seq += 1
+                               f"have {sorted(caps)}")
+        req_id = next(self._req_ids)
         span.set_metadata(req=req_id)
         if self._faults is not None:
             self._faults.on_submit(req_id)       # may raise InjectedOOM
@@ -708,98 +718,88 @@ class GraphStreamEngine:
             node_feat, senders, receivers, edge_feat = (
                 self._faults.corrupt_input(req_id, node_feat, senders,
                                            receivers, edge_feat))
+        node_feat = np.asarray(node_feat)
+        senders = np.asarray(senders)
+        receivers = np.asarray(receivers)
         if self._validate_inputs:
             # defense layer 1 (DESIGN.md §9): cheap vectorized admission
             # checks; a malformed graph fails HERE, typed and carrying its
-            # request id, instead of poisoning a packed batch downstream.
-            # edge_feat_dim 1 means "model takes no edge features" — any
-            # provided width is legal there (it is ignored), so the width
-            # check only binds when the model consumes edge features.
+            # request id, instead of poisoning a packed batch downstream
             with spans.span(spans.VALIDATE):
                 reason = check_graph(
                     node_feat, senders, receivers, edge_feat, node_pos,
-                    node_feat_dim=self.cfg.node_feat_dim,
-                    edge_feat_dim=(self.cfg.edge_feat_dim
-                                   if self.cfg.edge_feat_dim != 1 else None),
-                    pos_dim=self.cfg.pos_dim,
-                    require_finite=self._require_finite)
+                    **self._graph_dims, require_finite=self._require_finite)
             if reason is not None:
-                with self._cv:
-                    self.stats.invalid_rejects += 1
-                raise InvalidGraph(reason, request_ids=(req_id,))
-        n_nodes = int(np.asarray(node_feat).shape[0])
-        n_edges = int(np.asarray(senders).shape[0])
+                raise self._refused(InvalidGraph(reason,
+                                                 request_ids=(req_id,)))
+        n_nodes = node_feat.shape[0]
+        n_edges = senders.shape[0]
         # the model's own per-graph limit (GPS's attention slot): checked
         # whatever validate_inputs says, as nothing downstream can serve it
         reason = check_graph_nodes(n_nodes, self.cfg.attention_slot)
         if reason is not None:
-            with self._cv:
-                self.stats.invalid_rejects += 1
-            raise InvalidGraph(reason, request_ids=(req_id,))
+            raise self._refused(InvalidGraph(reason, request_ids=(req_id,)))
         # single-device budget gate (DESIGN.md §10): a graph no bucket can
         # hold is servable only by splitting it across a gang of executors
-        node_budget = max(self.buckets)
         wide_plan: Optional[WidePlan] = None
-        if n_nodes > node_budget:
-            reason = check_budget(n_nodes, n_edges, node_budget=node_budget,
+        if n_nodes > self._node_budget:
+            reason = check_budget(n_nodes, n_edges,
+                                  node_budget=self._node_budget,
                                   wide_enabled=self._wide_enabled)
             if not self._wide_enabled:
-                with self._cv:
-                    self.stats.invalid_rejects += 1
-                raise GraphTooLarge(reason, request_ids=(req_id,))
+                raise self._refused(GraphTooLarge(reason,
+                                                  request_ids=(req_id,)))
             try:
-                wide_plan = plan_wide(
-                    np.asarray(senders), np.asarray(receivers), n_nodes,
-                    k=self._wide_k, node_budget=node_budget)
+                wide_plan = plan_wide(senders, receivers, n_nodes,
+                                      k=self._wide_k,
+                                      node_budget=self._node_budget)
             except WidePlanError as exc:
-                with self._cv:
-                    self.stats.invalid_rejects += 1
-                raise GraphTooLarge(
+                raise self._refused(GraphTooLarge(
                     f"graph does not fit a {self._wide_k}-shard wide "
-                    f"split: {exc}", request_ids=(req_id,)) from exc
+                    f"split: {exc}", request_ids=(req_id,))) from exc
         t_arrival = time.perf_counter()
+        deadline_t = None if deadline is None else t_arrival + deadline
         fut: Future = Future()
         req = _Request(future=fut, record=record, req_id=req_id, queue=queue,
-                       deadline_t=(None if deadline is None
-                                   else t_arrival + deadline))
+                       deadline_t=deadline_t)
         item = (None if wide_plan is not None else
                 PackItem(node_feat=node_feat, senders=senders,
                          receivers=receivers, edge_feat=edge_feat,
-                         node_pos=node_pos, payload=req,
-                         t_arrival=t_arrival))
+                         node_pos=node_pos, payload=req, t_arrival=t_arrival,
+                         num_nodes=n_nodes, num_edges=n_edges))
         self._ensure_threads()
-        cap = self._queue_caps[queue]
+        cap = caps[queue]
+        pending = self._pending_by_queue
         with self._cv:
-            admitted = lambda: (self._pending_by_queue[queue] < cap
-                                or self._closed)
-            if req.deadline_t is None:
-                self._cv.wait_for(admitted)
-            else:
-                # the admission-vs-deadline hole (DESIGN.md §8): the
-                # deadline clock started at t_arrival, so the wait is
-                # bounded by the REMAINING budget — wait_for re-arms
-                # across spurious wakeups until room or timeout
-                self._cv.wait_for(
-                    admitted,
-                    timeout=max(req.deadline_t - time.perf_counter(), 0.0))
+            if pending[queue] >= cap and not self._closed:
+                admitted = lambda: pending[queue] < cap or self._closed
+                if deadline_t is None:
+                    self._cv.wait_for(admitted)
+                else:
+                    # the admission-vs-deadline hole (DESIGN.md §8): the
+                    # deadline clock started at t_arrival, so the wait is
+                    # bounded by the REMAINING budget — wait_for re-arms
+                    # across spurious wakeups until room or timeout
+                    self._cv.wait_for(
+                        admitted,
+                        timeout=max(deadline_t - time.perf_counter(), 0.0))
             if self._closed:
                 raise EngineClosed("engine is closed")
-            if req.deadline_t is not None and (
-                    self._pending_by_queue[queue] >= cap
-                    or time.perf_counter() >= req.deadline_t):
+            if deadline_t is not None and (
+                    pending[queue] >= cap
+                    or time.perf_counter() >= deadline_t):
                 # budget burned at backpressure (or expired the instant
                 # room appeared): shed now — never admit, never dispatch
                 self.stats.record_failure(queue=queue, shed=1, failed=1)
-                expired_req = req
+                expired = True
             else:
-                expired_req = None
+                expired = False
                 self._pending += 1
-                self._pending_by_queue[queue] += 1
+                pending[queue] += 1
                 self._requests[req_id] = req
-                if req.deadline_t is not None:
+                if deadline_t is not None:
                     self._deadlines_used = True
-                    heapq.heappush(self._deadline_heap,
-                                   (req.deadline_t, req_id))
+                    heapq.heappush(self._deadline_heap, (deadline_t, req_id))
                 if wide_plan is not None:
                     self._wide_queue.append(_WideRequest(
                         req=req, plan=wide_plan,
@@ -810,13 +810,19 @@ class GraphStreamEngine:
                                   np.asarray(node_pos, np.float32)),
                         t_arrival=t_arrival))
                 else:
-                    self._scheduler.add(queue, item, now=item.t_arrival)
+                    self._scheduler.add(queue, item, now=t_arrival)
             self._cv.notify_all()
-        if expired_req is not None:
+        if expired:
             _resolve(fut, exc=DeadlineExceeded(
                 "deadline expired at admission backpressure",
                 request_ids=(req_id,)))
         return fut
+
+    def _refused(self, exc: Exception) -> Exception:
+        """Count one graph refused at admission; returns ``exc`` to raise."""
+        with self._cv:
+            self.stats.invalid_rejects += 1
+        return exc
 
     def process(self, node_feat: np.ndarray, senders: np.ndarray,
                 receivers: np.ndarray, edge_feat: Optional[np.ndarray] = None,

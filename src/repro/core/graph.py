@@ -210,18 +210,19 @@ class FlatLayout:
     def pack(self, graphs: Sequence) -> np.ndarray:
         """The padded batch of ``graphs`` in one new int32 buffer (host).
 
-        ``graphs`` are objects with ``node_feat / senders / receivers`` and
-        optional ``edge_feat / node_pos``, packed in order with edge indices
-        shifted by each graph's node offset; a graph without an optional
-        field gets zeros, as in ``concat_raw_graphs``. A field of another
+        ``graphs`` are ``PackItem``s (``node_feat / senders / receivers``,
+        their ``num_nodes / num_edges`` and optional ``edge_feat /
+        node_pos``), packed in order with edge indices shifted by each
+        graph's node offset; a graph without an optional field gets
+        zeros, as in ``concat_raw_graphs``. A field of another
         width than the layout's raises, with one exception: where
         ``edge_feat_dim`` is 1 the model takes no edge features, admission
         lets any width through, and an ``edge_feat`` of another width is
         left out. The buffer is new on every call: a device put of it is
         asynchronous, so it must not be written again once handed over.
         """
-        nodes = np.array([g.node_feat.shape[0] for g in graphs], np.int32)
-        edges = np.array([g.senders.shape[0] for g in graphs], np.int64)
+        nodes = np.array([g.num_nodes for g in graphs], np.int32)
+        edges = np.array([g.num_edges for g in graphs], np.int64)
         n, e, n_graphs = int(nodes.sum()), int(edges.sum()), len(graphs)
         _check_fits(n, e, n_graphs, self.node_pad, self.edge_pad,
                     self.graph_pad)

@@ -38,7 +38,11 @@ DEFAULT_BUCKETS = (32, 64, 128, 256, 512, 1024)
 
 @dataclass
 class PackItem:
-    """One arriving graph plus the caller's opaque payload (e.g. a Future)."""
+    """One arriving graph plus the caller's opaque payload (e.g. a Future).
+
+    ``num_nodes`` / ``num_edges`` are read from the arrays' shapes once,
+    here, unless the caller (the engine's admission, which has read them
+    already) passes them."""
 
     node_feat: np.ndarray
     senders: np.ndarray
@@ -47,14 +51,14 @@ class PackItem:
     node_pos: Optional[np.ndarray] = None
     payload: Any = None
     t_arrival: float = field(default_factory=time.perf_counter)
+    num_nodes: int = -1
+    num_edges: int = -1
 
-    @property
-    def num_nodes(self) -> int:
-        return int(self.node_feat.shape[0])
-
-    @property
-    def num_edges(self) -> int:
-        return int(self.senders.shape[0])
+    def __post_init__(self):
+        if self.num_nodes < 0:
+            self.num_nodes = int(self.node_feat.shape[0])
+        if self.num_edges < 0:
+            self.num_edges = int(self.senders.shape[0])
 
 
 @dataclass

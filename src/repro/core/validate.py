@@ -26,8 +26,16 @@ import numpy as np
 from repro.core.errors import InvalidGraph
 
 
-def _is_int_dtype(a: np.ndarray) -> bool:
-    return np.issubdtype(np.asarray(a).dtype, np.integer)
+def _indices_below(idx: np.ndarray, n_nodes: int) -> bool:
+    """Whether every entry of the non-empty integer array ``idx`` lies in
+    ``[0, n_nodes)``, by one reduction: viewed as the unsigned type of its
+    width a negative entry reads ``>= 2**(bits - 1)``, which no signed
+    entry in range reaches."""
+    dt = idx.dtype
+    if dt.kind == "i":
+        n_nodes = min(n_nodes, 1 << (8 * dt.itemsize - 1))
+        idx = idx.view(dt.str.replace("i", "u"))
+    return int(np.maximum.reduce(idx)) < n_nodes
 
 
 def check_graph(node_feat, senders, receivers, edge_feat=None,
@@ -43,9 +51,12 @@ def check_graph(node_feat, senders, receivers, edge_feat=None,
     * shapes: ``node_feat`` is a non-empty 2-D array; ``senders`` /
       ``receivers`` are 1-D and the same length (zero edges is legal —
       an isolated node is a real molecule);
-    * index dtypes: integer (a float edge list silently truncates);
+    * index dtypes: signed or unsigned integer (a float edge list
+      silently truncates; ``bool`` and ``timedelta64`` are refused too);
     * index range: every sender/receiver in ``[0, n_nodes)`` — the check
-      that prevents cross-graph reads after packing offsets are applied;
+      that prevents cross-graph reads after packing offsets are applied.
+      An admissible graph pays one reduction per index array; the range
+      the message quotes is computed only for a graph that fails it;
     * feature widths vs the model config (``node_feat_dim`` /
       ``edge_feat_dim`` / ``pos_dim`` — pass ``None`` to skip one);
       ``edge_feat`` rows must match the edge count;
@@ -71,12 +82,14 @@ def check_graph(node_feat, senders, receivers, edge_feat=None,
         return (f"senders ({senders.shape[0]}) and receivers "
                 f"({receivers.shape[0]}) disagree on the edge count")
     if senders.size:
-        if not _is_int_dtype(senders) or not _is_int_dtype(receivers):
+        if (senders.dtype.kind not in "iu"
+                or receivers.dtype.kind not in "iu"):
             return (f"edge indices must be integers, got "
                     f"{senders.dtype}/{receivers.dtype}")
-        lo = min(int(senders.min()), int(receivers.min()))
-        hi = max(int(senders.max()), int(receivers.max()))
-        if lo < 0 or hi >= n_nodes:
+        if not (_indices_below(senders, n_nodes)
+                and _indices_below(receivers, n_nodes)):
+            lo = min(int(senders.min()), int(receivers.min()))
+            hi = max(int(senders.max()), int(receivers.max()))
             return (f"edge index out of range: [{lo}, {hi}] not within "
                     f"[0, {n_nodes})")
 
